@@ -1,50 +1,14 @@
-"""Small JAX version-compat shims.
-
-The repo targets current JAX but must degrade gracefully on older releases
-(the CI image pins one).  Kernels carry their own CompilerParams alias; this
-module holds the shared mesh helper.
-"""
+"""The mesh helper shared by the launchers and the tests."""
 
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
-
-
-def axis_size(axis_name: str) -> int:
-    """jax.lax.axis_size, with the classic psum-of-1 idiom as fallback.
-
-    `lax.psum(1, axis)` constant-folds to the concrete axis size on releases
-    that predate `lax.axis_size`, so both paths return a static int.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a dict across JAX versions.
-
-    Releases before ~0.5 return a single-element list of per-device
-    dicts; newer releases return the dict directly.  Either way the
-    caller wants one mapping of cost keys.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """jax.make_mesh with explicitly-Auto axis types where supported.
-
-    Newer JAX grew an `axis_types` kwarg (default Auto); older releases
-    don't accept it.  All our meshes are Auto, so both spellings agree.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """jax.make_mesh with every axis explicitly Auto (all our meshes are)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -29,6 +29,13 @@ Schedules (the loop-order family `kernels.skew_matmul` implements):
                  B streamed once, A per n-block, C revisited per k-block.
                  Wins for left-skewed (m >> n) shapes.
 
+These are modeled charges.  The kernels, since they first ran on a TPU,
+differ: the resident schedules at gk > 1 keep their partial sums in an
+fp32 VMEM strip of the whole inner extent and write C once, and every
+kernel double-buffers its output block, so `BlockPlan.vmem_bytes`
+undercounts what a kernel allocates (kernels.skew_matmul.compiler_params
+sizes the real allocation).
+
 The GEMV family (`GEMV_SCHEDULES`) covers the decode regime — the paper's
 right-skew limit, m a handful of rows against tens of thousands of cache
 columns — where no dense loop order can feed the matrix engine:

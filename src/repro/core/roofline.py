@@ -22,7 +22,6 @@ import dataclasses
 import json
 import re
 
-from repro import compat
 from repro.core import hw
 
 # bytes-on-wire multiplier per collective, ring algorithm, large-N limit:
@@ -169,7 +168,7 @@ def analyze(compiled, hlo_text: str, *, arch: str, shape: str, mesh: str,
     """
     if ici_links is None:
         ici_links = chip.ici_links
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     hbm_bytes = float(ca.get("bytes accessed", 0.0))
     coll = collective_stats(hlo_text)

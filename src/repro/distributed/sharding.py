@@ -100,9 +100,9 @@ def param_spec(path_names: list[str], leaf, mesh: Mesh) -> P:
 
     if "moe" in path_names and name in _EXPERT and ndim >= 3:
         return base(P("model", None, None), 3)
-    if name in _VOCAB_ROW:
+    if name in _VOCAB_ROW and ndim >= 2:
         return base(P("model", None), 2)
-    if name in _VOCAB_COL:
+    if name in _VOCAB_COL and ndim >= 2:
         return base(P(None, "model"), 2)
     if name in _BLOCKDIAG and ndim >= 3:
         return base(P("model", None, None), 3)

@@ -41,8 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import epilogue as epilogue_mod
-from repro.kernels.skew_matmul import (_CompilerParams, _apply_epilogue,
-                                       _epilogue_refs)
+from repro.kernels.skew_matmul import (_apply_epilogue, _epilogue_refs,
+                                       compiler_params, epilogue_blocks)
 
 
 def tree_sum(parts):
@@ -110,8 +110,11 @@ def gemv_splitk_padded(a: jax.Array, b: jax.Array, bias=None, residual=None,
         ],
         out_specs=pl.BlockSpec((1, m, bn), lambda s, j: (s, 0, j)),
         out_shape=jax.ShapeDtypeStruct((gk, m, n), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=compiler_params(
+            ("parallel", "parallel"),
+            pipelined=[((m, bk), a.dtype), ((bk, bn), b.dtype),
+                       ((m, bn), jnp.float32)],
+            resident=[((m, bn), jnp.float32)]),
         interpret=interpret,
     )(a, b)
 
@@ -135,6 +138,11 @@ def gemv_splitk_padded(a: jax.Array, b: jax.Array, bias=None, residual=None,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=compiler_params(
+            ("parallel",),
+            pipelined=[((gk, m, bn), jnp.float32), ((m, bn), out_dtype),
+                       *epilogue_blocks(tokens, bias, residual, m, bn)],
+            # the tree's levels: gk/2 + gk/4 + ... partial slabs
+            resident=[((gk, m, bn), jnp.float32)]),
         interpret=interpret,
     )(*operands)

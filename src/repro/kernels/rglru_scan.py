@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def _rglru_kernel(x_ref, r_ref, i_ref, lam_ref, y_ref, h_ref, *, c: float,
                   chunk: int):
@@ -89,7 +85,7 @@ def rglru_scan(x: jax.Array, r_gate: jax.Array, i_gate: jax.Array,
         out_specs=pl.BlockSpec((1, 1, chunk, d), lambda bb, cc: (bb, cc, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_chunks, chunk, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xr, rr, ir, a_param)

@@ -14,11 +14,11 @@ operand is re-streamed and which stays VMEM-resident):
                  m-block: the C-write-once / A,B-revisit pattern.
   "a_resident" — grid (m, k, n), N innermost and sequential.  The A block is
                  pinned in VMEM across the whole n sweep (streamed exactly
-                 once); the output block is revisited per k-block and
-                 accumulated in-place (fp32-wide while gk > 1).  The planner
-                 picks this for right-skewed (m << n) shapes — the LM-head /
-                 vocab-projection class — where re-streaming A per n-block is
-                 the dominant waste.
+                 once).  While gk > 1 the partial products of the whole
+                 (bm, n) row strip accumulate in an fp32 VMEM scratch.  The
+                 planner picks this for right-skewed (m << n) shapes — the
+                 LM-head / vocab-projection class — where re-streaming A per
+                 n-block is the dominant waste.
   "b_resident" — grid (n, k, m), M innermost; the mirror image.  B streamed
                  once; chosen for left-skewed (m >> n) shapes.
 
@@ -34,17 +34,19 @@ spec*: the hashable tuple from `Epilogue.spec` (the structured surface in
 repro.core.epilogue — how ops.py calls in) or a legacy underscore-joined
 token string, e.g. "bias_gelu"; the bias / residual operands must be passed
 iff named.  The op semantics live in ONE table (epilogue.EPILOGUE_OPS)
-shared with the XLA backend and the jnp oracle.  For the resident schedules
-with gk > 1 the kernel accumulates through an fp32 output which is cast
-back to ``out_dtype`` outside the pallas_call (the cost model charges that
-extra pass).
+shared with the XLA backend and the jnp oracle.
 
-Note on the resident schedules: the output block index recurs
-non-consecutively across the k grid dim, so both the k and inner dims are
-marked "arbitrary" (sequential) and correctness relies on Pallas's
-write-back / re-fetch of revisited output blocks.  When gk == 1 (the common
-case the planner targets: the whole contraction in one block) there is no
-revisit at all.
+Note on the resident schedules: on the TPU an output block is written back
+when its block index changes and is never read back, so a kernel must not
+accumulate through an output block it revisits non-consecutively.  With
+gk > 1 the resident kernels keep the partial sums of the whole inner strip
+in an fp32 scratch, and their output index map holds the block index still
+until the last k step, so each output block is written back once.  When
+gk == 1 (the case the planner picks at real sizes: the whole contraction
+in one block) there is no revisit at all.
+
+Every kernel states its scoped-VMEM limit (`compiler_params`): Mosaic's
+default limit is far below the blocks the planner admits.
 """
 
 from __future__ import annotations
@@ -63,9 +65,59 @@ from repro.core import epilogue as epilogue_mod
 # Legacy re-export for kernel-level callers of the string surface.
 from repro.core.skewmm import parse_epilogue  # noqa: E402, F401
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+# Physical VMEM of one TPU v5e TensorCore.  Mosaic compiles a kernel under
+# a scoped-VMEM limit far below it unless the kernel asks for more.
+VMEM_CAPACITY_BYTES = 128 * 1024**2
+# Never ask for less than Mosaic's own default limit.
+_VMEM_FLOOR_BYTES = 32 * 1024**2
+# Mosaic's internal scratch, on top of the buffers the kernel names.
+_VMEM_MARGIN_BYTES = 4 * 1024**2
+
+
+def vmem_buffer_bytes(shape, dtype) -> int:
+    """VMEM bytes of one buffer: its minor two dims padded to the
+    (sublane, lane) tile of its dtype (8 x 128 words, packed for narrow
+    dtypes)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = 8 * max(1, 4 // itemsize)
+    n = -(-rows // sub) * sub * (-(-cols // 128) * 128) * itemsize
+    for d in lead:
+        n *= d
+    return n
+
+
+def compiler_params(semantics, *, pipelined,
+                    resident=()) -> pltpu.CompilerParams:
+    """CompilerParams whose scoped-VMEM limit covers what the kernel
+    allocates.
+
+    `pipelined` lists the (block shape, dtype) of every input and output
+    block; Pallas double-buffers each.  `resident` lists single buffers:
+    scratch, and the fp32 values the body materializes (a dot result).
+    A kernel that cannot fit the chip's VMEM raises here, at trace time,
+    instead of failing inside the compiler.
+    """
+    need = (2 * sum(vmem_buffer_bytes(s, d) for s, d in pipelined)
+            + sum(vmem_buffer_bytes(s, d) for s, d in resident))
+    limit = max(_VMEM_FLOOR_BYTES, need + _VMEM_MARGIN_BYTES)
+    if limit > VMEM_CAPACITY_BYTES:
+        raise ValueError(
+            f"kernel needs {need} B of VMEM plus a {_VMEM_MARGIN_BYTES} "
+            f"B margin, more than the {VMEM_CAPACITY_BYTES} B of one "
+            f"TPU v5e core")
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
+
+
+def epilogue_blocks(tokens, bias, residual, bm: int, bn: int) -> list:
+    """(block shape, dtype) of the epilogue operands a kernel streams."""
+    out = []
+    if "bias" in tokens:
+        out.append(((1, bn), bias.dtype))
+    if "residual" in tokens:
+        out.append(((bm, bn), residual.dtype))
+    return out
 
 
 def _apply_epilogue(z, spec, bias_ref, res_ref):
@@ -85,6 +137,38 @@ def _epilogue_refs(refs, tokens):
     bias_ref = next(it) if "bias" in tokens else None
     res_ref = next(it) if "residual" in tokens else None
     return bias_ref, res_ref
+
+
+def strip_accumulate(partial, strip_ref, k_step, n_k_steps: int, flush):
+    """Accumulate `partial` into slot `pl.program_id(2)` of an fp32 strip
+    scratch over the k steps (grid axis 1); on the last step hand the
+    full sum to `flush`.  Shared by the dense and block-sparse resident
+    schedules, whose inner grid axis (2) indexes the strip."""
+    slot = pl.program_id(2)
+
+    @pl.when(k_step == 0)
+    def _first():
+        strip_ref[slot] = partial
+
+    @pl.when(jnp.logical_and(k_step > 0, k_step < n_k_steps - 1))
+    def _middle():
+        strip_ref[slot] += partial
+
+    @pl.when(k_step == n_k_steps - 1)
+    def _last():
+        flush(strip_ref[slot] + partial)
+
+
+def held_until_last(index_map, n_k_steps: int, inner_pos: int):
+    """Wrap an output index map of a (outer, k, inner) grid so the block
+    index stays at inner block 0 until the last k step.  The output block
+    then changes — and is written back — only after its one real write."""
+    def held(*idx):
+        blocks = list(index_map(*idx))
+        last = idx[1] == n_k_steps - 1
+        blocks[inner_pos] = jnp.where(last, blocks[inner_pos], 0)
+        return tuple(blocks)
+    return held
 
 
 # --------------------------------------------------------------- kernel bodies
@@ -112,32 +196,26 @@ def _k_inner_kernel(*refs, spec, n_k_steps: int, k_axis: int):
 
 
 def _resident_kernel(*refs, spec, n_k_steps: int):
-    """Shared body for a_resident / b_resident: k is the *middle* grid dim,
-    so partial products accumulate through the revisited output block."""
+    """Shared body for a_resident / b_resident: k is the *middle* grid dim
+    and the inner dim (grid axis 2) indexes the fp32 strip scratch that
+    carries the partial sums while gk > 1."""
     tokens = tuple(t for t, _ in spec)
     a_ref, b_ref, *rest = refs
+    if n_k_steps > 1:
+        *rest, strip_ref = rest
     o_ref = rest[-1]
     bias_ref, res_ref = _epilogue_refs(rest[:-1], tokens)
     partial = jnp.dot(a_ref[...], b_ref[...],
                       preferred_element_type=jnp.float32)
-    if n_k_steps == 1:
-        z = _apply_epilogue(partial, spec, bias_ref, res_ref)
+
+    def flush(acc):
+        z = _apply_epilogue(acc, spec, bias_ref, res_ref)
         o_ref[...] = z.astype(o_ref.dtype)
+
+    if n_k_steps == 1:
+        flush(partial)
         return
-    k_step = pl.program_id(1)
-
-    @pl.when(k_step == 0)
-    def _first():
-        o_ref[...] = partial
-
-    @pl.when(jnp.logical_and(k_step > 0, k_step < n_k_steps - 1))
-    def _middle():
-        o_ref[...] += partial
-
-    @pl.when(k_step == n_k_steps - 1)
-    def _last():
-        z = _apply_epilogue(o_ref[...] + partial, spec, bias_ref, res_ref)
-        o_ref[...] = z
+    strip_accumulate(partial, strip_ref, pl.program_id(1), n_k_steps, flush)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "schedule",
@@ -172,6 +250,10 @@ def skew_matmul_padded(a: jax.Array, b: jax.Array, bias=None, residual=None,
         assert residual is not None and residual.shape == (m, n), (
             "epilogue names 'residual': pass a pre-padded (m, n) array")
         operands.append(residual)
+    pipelined = [((bm, bk), a.dtype), ((bk, bn), b.dtype),
+                 ((bm, bn), out_dtype),
+                 *epilogue_blocks(tokens, bias, residual, bm, bn)]
+    acc = ((bm, bn), jnp.float32)
 
     if schedule == "k_inner":
         grid = (gm, gn, gk)
@@ -191,8 +273,9 @@ def skew_matmul_padded(a: jax.Array, b: jax.Array, bias=None, residual=None,
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            compiler_params=compiler_params(
+                ("parallel", "parallel", "arbitrary"), pipelined=pipelined,
+                resident=[acc, acc]),
             interpret=interpret,
         )(*operands)
 
@@ -207,8 +290,7 @@ def skew_matmul_padded(a: jax.Array, b: jax.Array, bias=None, residual=None,
             in_specs.append(pl.BlockSpec((1, bn), lambda i, kk, j: (0, j)))
         if "residual" in tokens:
             in_specs.append(pl.BlockSpec((bm, bn), lambda i, kk, j: (i, j)))
-        out_spec = pl.BlockSpec((bm, bn), lambda i, kk, j: (i, j))
-        semantics = ("parallel", "arbitrary", "arbitrary")
+        out_map, inner_pos, n_inner = (lambda i, kk, j: (i, j)), 1, gn
     elif schedule == "b_resident":
         # grid (n, k, m): m innermost — B block pinned across the m sweep.
         grid = (gn, gk, gm)
@@ -220,23 +302,27 @@ def skew_matmul_padded(a: jax.Array, b: jax.Array, bias=None, residual=None,
             in_specs.append(pl.BlockSpec((1, bn), lambda j, kk, i: (0, j)))
         if "residual" in tokens:
             in_specs.append(pl.BlockSpec((bm, bn), lambda j, kk, i: (i, j)))
-        out_spec = pl.BlockSpec((bm, bn), lambda j, kk, i: (i, j))
-        semantics = ("parallel", "arbitrary", "arbitrary")
+        out_map, inner_pos, n_inner = (lambda j, kk, i: (i, j)), 0, gm
     else:
         raise ValueError(f"unknown schedule {schedule!r}")
 
-    # gk > 1 accumulates through the output at f32; cast back outside.
-    acc_dtype = out_dtype if gk == 1 else jnp.float32
-    out = pl.pallas_call(
+    scratch, resident = [], [acc]
+    if gk > 1:
+        out_map = held_until_last(out_map, gk, inner_pos)
+        scratch = [pltpu.VMEM((n_inner, bm, bn), jnp.float32)]
+        resident.append(((n_inner, bm, bn), jnp.float32))
+    return pl.pallas_call(
         functools.partial(_resident_kernel, spec=spec, n_k_steps=gk),
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), acc_dtype),
-        compiler_params=_CompilerParams(dimension_semantics=semantics),
+        out_specs=pl.BlockSpec((bm, bn), out_map),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        scratch_shapes=scratch,
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), pipelined=pipelined,
+            resident=resident),
         interpret=interpret,
     )(*operands)
-    return out.astype(out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "epilogue",
@@ -275,6 +361,10 @@ def skew_matmul_batched_padded(a: jax.Array, b: jax.Array, bias=None,
         operands.append(residual)
         in_specs.append(
             pl.BlockSpec((1, bm, bn), lambda nb_, i, j, kk: (nb_, i, j)))
+    pipelined = [((bm, bk), a.dtype), ((bk, bn), b.dtype),
+                 ((bm, bn), out_dtype),
+                 *epilogue_blocks(tokens, bias, residual, bm, bn)]
+    acc = ((bm, bn), jnp.float32)
 
     return pl.pallas_call(
         functools.partial(_k_inner_kernel, spec=spec, n_k_steps=gk,
@@ -284,8 +374,8 @@ def skew_matmul_batched_padded(a: jax.Array, b: jax.Array, bias=None,
         out_specs=pl.BlockSpec((1, bm, bn), lambda nb_, i, j, kk: (nb_, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary"),
+            pipelined=pipelined, resident=[acc, acc]),
         interpret=interpret,
     )(*operands)

@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref, *,
                 chunk: int):
@@ -109,7 +105,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a_log: jax.Array, b_mat: jax.Array,
                                lambda bb, hh, cc: (bb, hh, cc, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, h, length, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((s, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a_log, xt, dtt, bt, ct)

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import all_arch_ids, get_config
 from repro.core import config as mmcfg
 from repro.core import roofline
@@ -87,7 +86,7 @@ ZERO = ProbeCost(0.0, 0.0, 0.0, {})
 def _measure(fn, *sds_args, out_shardings=None) -> ProbeCost:
     lowered = jax.jit(fn, out_shardings=out_shardings).lower(*sds_args)
     compiled = lowered.compile()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     cs = roofline.collective_stats(compiled.as_text())
     return ProbeCost(float(ca.get("flops", 0.0)),
                      float(ca.get("bytes accessed", 0.0)),
@@ -590,4 +589,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     main()

@@ -181,4 +181,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     raise SystemExit(main())

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -32,7 +33,7 @@ def build_model(cfg: ModelConfig | str) -> ModelBundle:
                                          batch["frames"])
         return ModelBundle(
             cfg=cfg,
-            init=lambda key: encdec.init_encdec(cfg, key),
+            init=jax.jit(functools.partial(encdec.init_encdec, cfg)),
             hidden_fn=hidden_fn,
             logits_fn=lambda p, h: transformer.unembed(p, cfg, h),
         )
@@ -44,7 +45,7 @@ def build_model(cfg: ModelConfig | str) -> ModelBundle:
 
     return ModelBundle(
         cfg=cfg,
-        init=lambda key: transformer.init_lm(cfg, key),
+        init=jax.jit(functools.partial(transformer.init_lm, cfg)),
         hidden_fn=hidden_fn,
         logits_fn=lambda p, h: transformer.unembed(p, cfg, h),
     )

@@ -16,11 +16,11 @@ from repro.models import blocks, layers
 from repro.models.layers import embed_init, linear_init, rmsnorm
 
 
-def _stack(trees: list) -> dict:
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def init_lm(cfg, key) -> dict:
+    """Seeded parameters.  Each stage's repeating units are built by one
+    `vmap` over their keys, so the stacked layout is produced directly
+    (no per-layer copies to stack); `build_model` runs this under
+    `jax.jit`, so no float32 draw outlives its cast."""
     dt = layers.dtype_of(cfg)
     keys = jax.random.split(key, 8)
     params: dict = {
@@ -32,13 +32,13 @@ def init_lm(cfg, key) -> dict:
                                         cfg.vocab_size, dt)
     stage_keys = jax.random.split(keys[2], len(cfg.stage_list()))
     for si, (unit, n) in enumerate(cfg.stage_list()):
-        reps = []
-        rkeys = jax.random.split(stage_keys[si], n)
-        for r in range(n):
-            ukeys = jax.random.split(rkeys[r], len(unit))
-            reps.append({f"b{i}": blocks.init_block(ukeys[i], cfg, kind)
-                         for i, kind in enumerate(unit)})
-        params[f"stage{si}"] = _stack(reps)
+        def init_unit(ukey, unit=unit):
+            ukeys = jax.random.split(ukey, len(unit))
+            return {f"b{i}": blocks.init_block(ukeys[i], cfg, kind)
+                    for i, kind in enumerate(unit)}
+
+        params[f"stage{si}"] = jax.vmap(init_unit)(
+            jax.random.split(stage_keys[si], n))
     if cfg.mtp_heads:
         # deepseek-style MTP: next-next-token head = proj([h; emb]) + block
         params["mtp"] = {

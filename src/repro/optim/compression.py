@@ -16,8 +16,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 class EFState(NamedTuple):
     residual: Any          # fp32 pytree like grads
@@ -52,7 +50,7 @@ def int8_ring_allreduce(x: jax.Array, axis_name: str) -> jax.Array:
     Quantization error is O(n) quantization steps; pair with error
     feedback (compress_grads) so the residual re-enters the next step.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = jax.lax.axis_index(axis_name)

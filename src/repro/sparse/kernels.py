@@ -15,8 +15,9 @@ accumulation order, same fused-epilogue flush):
   "k_inner"    — grid (gm, gn, s); fp32 VMEM scratch accumulator,
                  output written once on the last s step.
   "a_resident" — grid (gm, s, gn); each nonzero A block pinned across
-                 the n sweep, output revisited per s (fp32-wide while
-                 s_max > 1, cast back outside the pallas_call).
+                 the n sweep; while s_max > 1 the row strip's partial
+                 sums live in an fp32 scratch and each output block is
+                 written once, on the last s step.
   "b_resident" — grid (gn, s, gm); kept for schedule parity.  With
                  row-major (CSR) structure the B block index varies with
                  the inner row index, so B is *not* actually resident —
@@ -45,12 +46,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import epilogue as epilogue_mod
 
-# One definition of the epilogue flush + the CompilerParams alias, shared
-# with the dense kernels so the two families cannot drift.
+# One definition of the epilogue flush, the strip accumulation and the
+# VMEM-limited CompilerParams, shared with the dense kernels so the two
+# families cannot drift.
 from repro.kernels.skew_matmul import (
     _apply_epilogue,
-    _CompilerParams,
     _epilogue_refs,
+    compiler_params,
+    epilogue_blocks,
+    held_until_last,
+    strip_accumulate,
 )
 
 
@@ -83,13 +88,15 @@ def _bsr_k_inner_kernel(cols_ref, nnz_ref, a_ref, b_ref, *rest, spec, s_steps):
 def _bsr_resident_kernel(
     cols_ref, nnz_ref, a_ref, b_ref, *rest, spec, s_steps, row_axis
 ):
-    """Shared a_resident / b_resident body: s is the middle grid dim,
-    partial products accumulate through the revisited output block.
-    Invalid tail steps contribute an exact zero (partial * 0.0), which
-    at density 1.0 degenerates to the dense body bit-for-bit
-    (partial * 1.0)."""
+    """Shared a_resident / b_resident body: s is the middle grid dim and
+    the inner dim indexes the fp32 strip scratch that carries the partial
+    sums while s_max > 1 (as in the dense resident kernels).  Invalid
+    tail steps contribute an exact zero (partial * 0.0), which at density
+    1.0 degenerates to the dense body bit-for-bit (partial * 1.0)."""
     del cols_ref
     tokens = tuple(t for t, _ in spec)
+    if s_steps > 1:
+        *rest, strip_ref = rest
     o_ref = rest[-1]
     bias_ref, res_ref = _epilogue_refs(rest[:-1], tokens)
     i = pl.program_id(row_axis)
@@ -98,23 +105,15 @@ def _bsr_resident_kernel(
     partial = flag * jnp.dot(
         a_ref[...], b_ref[...], preferred_element_type=jnp.float32
     )
-    if s_steps == 1:
-        z = _apply_epilogue(partial, spec, bias_ref, res_ref)
+
+    def flush(acc):
+        z = _apply_epilogue(acc, spec, bias_ref, res_ref)
         o_ref[...] = z.astype(o_ref.dtype)
+
+    if s_steps == 1:
+        flush(partial)
         return
-
-    @pl.when(s == 0)
-    def _first():
-        o_ref[...] = partial
-
-    @pl.when(jnp.logical_and(s > 0, s < s_steps - 1))
-    def _middle():
-        o_ref[...] += partial
-
-    @pl.when(s == s_steps - 1)
-    def _last():
-        z = _apply_epilogue(o_ref[...] + partial, spec, bias_ref, res_ref)
-        o_ref[...] = z
+    strip_accumulate(partial, strip_ref, s, s_steps, flush)
 
 
 def _grouped_k_inner_kernel(a_ref, b_ref, *rest, spec, n_k_steps):
@@ -202,6 +201,13 @@ def block_sparse_matmul_padded(
             "epilogue names 'residual': pass a pre-padded (m, n) array"
         )
         operands.append(residual)
+    pipelined = [
+        ((bm, bk), a.dtype),
+        ((bk, bn), b.dtype),
+        ((bm, bn), out_dtype),
+        *epilogue_blocks(tokens, bias, residual, bm, bn),
+    ]
+    acc = ((bm, bn), jnp.float32)
 
     if schedule == "k_inner":
         grid = (gm, gn, s_steps)
@@ -224,8 +230,10 @@ def block_sparse_matmul_padded(
             functools.partial(_bsr_k_inner_kernel, spec=spec, s_steps=s_steps),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
+            compiler_params=compiler_params(
+                ("parallel", "parallel", "arbitrary"),
+                pipelined=pipelined,
+                resident=[acc, acc],
             ),
             interpret=interpret,
         )(cols, nnz, *operands)
@@ -242,7 +250,7 @@ def block_sparse_matmul_padded(
             in_specs.append(pl.BlockSpec((1, bn), lambda i, s, j, cols, nnz: (0, j)))
         if "residual" in tokens:
             in_specs.append(pl.BlockSpec((bm, bn), lambda i, s, j, cols, nnz: (i, j)))
-        out_spec = pl.BlockSpec((bm, bn), lambda i, s, j, cols, nnz: (i, j))
+        out_map, inner_pos, n_inner = (lambda i, s, j, cols, nnz: (i, j)), 1, gn
         row_axis = 0
     elif schedule == "b_resident":
         # grid (n, s, m): m innermost (see module docstring on residency).
@@ -255,20 +263,24 @@ def block_sparse_matmul_padded(
             in_specs.append(pl.BlockSpec((1, bn), lambda j, s, i, cols, nnz: (0, j)))
         if "residual" in tokens:
             in_specs.append(pl.BlockSpec((bm, bn), lambda j, s, i, cols, nnz: (i, j)))
-        out_spec = pl.BlockSpec((bm, bn), lambda j, s, i, cols, nnz: (i, j))
+        out_map, inner_pos, n_inner = (lambda j, s, i, cols, nnz: (i, j)), 0, gm
         row_axis = 2
     else:
         raise ValueError(f"unknown schedule {schedule!r}")
 
-    # s_steps > 1 accumulates through the output at f32; cast outside.
-    acc_dtype = out_dtype if s_steps == 1 else jnp.float32
+    scratch, resident = [], [acc]
+    if s_steps > 1:
+        out_map = held_until_last(out_map, s_steps, inner_pos)
+        scratch = [pltpu.VMEM((n_inner, bm, bn), jnp.float32)]
+        resident.append(((n_inner, bm, bn), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=pl.BlockSpec((bm, bn), out_map),
+        scratch_shapes=scratch,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _bsr_resident_kernel,
             spec=spec,
@@ -276,13 +288,14 @@ def block_sparse_matmul_padded(
             row_axis=row_axis,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), acc_dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"),
+            pipelined=pipelined,
+            resident=resident,
         ),
         interpret=interpret,
     )(cols, nnz, *operands)
-    return out.astype(out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=_GROUPED_STATIC_ARGS)
@@ -334,13 +347,15 @@ def grouped_matmul_padded(
         out_specs=pl.BlockSpec((1, bm, bn), lambda g_, i, j, kk: (g_, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=(
-                "parallel",
-                "parallel",
-                "parallel",
-                "arbitrary",
-            )
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary"),
+            pipelined=[
+                ((bm, bk), a.dtype),
+                ((bk, bn), b.dtype),
+                ((bm, bn), out_dtype),
+                *epilogue_blocks(tokens, None, residual, bm, bn),
+            ],
+            resident=[((bm, bn), jnp.float32)] * 2,
         ),
         interpret=interpret,
     )(*operands)

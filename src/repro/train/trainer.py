@@ -3,11 +3,12 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.ckpt import CheckpointManager
 from repro.distributed import sharding as shd
@@ -38,15 +39,16 @@ class Trainer:
         self.ckpt = CheckpointManager(cfg.ckpt_dir, keep=cfg.ckpt_keep)
         self.guard = StepGuard()
 
+        # The state is built by one jitted init that emits every leaf
+        # already sharded: no device ever holds the whole unsharded state.
         key = jax.random.PRNGKey(cfg.seed)
-        state = init_train_state(bundle, opt, key, ts_cfg)
-        self.state_specs = self._specs_for(state)
-        self.state = shd.shard_like(state, self.state_specs, mesh)
+        init = functools.partial(init_train_state, bundle, opt,
+                                 ts_cfg=ts_cfg)
+        self.state_specs = self._specs_for(jax.eval_shape(init, key))
+        state_sh = shd.named(self.state_specs, mesh)
+        self.state = jax.jit(init, out_shardings=state_sh)(key)
         step_fn = make_train_step(bundle, opt, ts_cfg)
-        out_sh = (jax.tree.map(lambda s: NamedSharding(mesh, s),
-                               self.state_specs,
-                               is_leaf=lambda x: isinstance(x, P)), None)
-        self.step_fn = jax.jit(step_fn, out_shardings=out_sh)
+        self.step_fn = jax.jit(step_fn, out_shardings=(state_sh, None))
 
     def _specs_for(self, state: TrainState) -> TrainState:
         p_specs = shd.tree_param_specs(state.params, self.mesh)
@@ -69,20 +71,23 @@ class Trainer:
         return step
 
     # --------------------------------------------------------------- run
+    def step(self, batch) -> dict:
+        """One guarded optimizer step on `batch`; returns its metrics."""
+        def one_step():
+            return retry_step(self.step_fn, self.state, batch)
+
+        (self.state, metrics), straggled = self.guard.run(one_step)
+        if straggled:
+            self.log("[trainer] straggler detected "
+                     "(would re-form mesh on real fleet)")
+        return metrics
+
     def run(self, loader) -> dict:
         start = self.maybe_restore()
         metrics_hist = []
         t0 = time.time()
         for step in range(start, self.cfg.total_steps):
-            batch = next(loader)
-
-            def one_step():
-                return retry_step(self.step_fn, self.state, batch)
-
-            (self.state, metrics), straggled = self.guard.run(one_step)
-            if straggled:
-                self.log(f"[trainer] step {step}: straggler detected "
-                         "(would re-form mesh on real fleet)")
+            metrics = self.step(next(loader))
             if (step + 1) % self.cfg.log_every == 0 or step == start:
                 loss = float(metrics["loss"])
                 rate = (step + 1 - start) / (time.time() - t0)

@@ -10,7 +10,7 @@ import pytest
 
 from repro.core.costmodel import BlockPlan
 from repro.core.epilogue import Epilogue
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, skew_matmul
 
 RNG = np.random.default_rng(42)
 
@@ -92,6 +92,52 @@ def test_resident_single_k_block(schedule):
     want = ref.matmul_epilogue_ref(a, b, epilogue="gelu")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=1e-4)
+
+
+@pytest.mark.parametrize("inner_pos,n_inner", [(1, 3), (0, 2), (1, 1)])
+def test_resident_output_blocks_written_once(inner_pos, n_inner):
+    """gk > 1: on the TPU an output block is written back when its index
+    changes and never read back, so every output block's visits must be
+    one consecutive run that ends on the last k step."""
+    gk, n_outer = 4, 2
+    if inner_pos == 1:     # a_resident: grid (m, k, n)
+        base = lambda i, kk, j: (i, j)  # noqa: E731
+    else:                  # b_resident: grid (n, k, m)
+        base = lambda j, kk, i: (i, j)  # noqa: E731
+    held = skew_matmul.held_until_last(base, gk, inner_pos)
+    visits = []
+    for outer in range(n_outer):
+        for kk in range(gk):
+            for inner in range(n_inner):
+                blk = tuple(int(x) for x in held(outer, kk, inner))
+                visits.append((blk, kk))
+    runs = []
+    for blk, kk in visits:
+        if runs and runs[-1][0] == blk:
+            runs[-1][1].append(kk)
+        else:
+            runs.append((blk, [kk]))
+    blocks = [blk for blk, _ in runs]
+    assert len(blocks) == len(set(blocks)) == n_outer * n_inner
+    assert all(ks[-1] == gk - 1 for _, ks in runs)
+
+
+def test_compiler_params_cover_buffers_and_capacity():
+    # the planner's k_inner (1024, 1536, 2048) bf16 plan: the cost model
+    # claims 26 MiB, the kernel double-buffers its output too
+    blocks = [((1024, 1536), jnp.bfloat16), ((1536, 2048), jnp.bfloat16),
+              ((1024, 2048), jnp.bfloat16)]
+    acc = ((1024, 2048), jnp.float32)
+    params = skew_matmul.compiler_params(
+        ("parallel", "parallel", "arbitrary"), pipelined=blocks,
+        resident=[acc, acc])
+    assert params.vmem_limit_bytes >= (2 * (3 + 6 + 4) + 8 + 8) * 2**20
+    assert params.vmem_limit_bytes <= skew_matmul.VMEM_CAPACITY_BYTES
+    assert skew_matmul.vmem_buffer_bytes((1, 2048), jnp.bfloat16) == \
+        16 * 2048 * 2      # a row pads to the bf16 (16, 128) tile
+    huge = [((8192, 4096), jnp.float32)]
+    with pytest.raises(ValueError, match="VMEM"):
+        skew_matmul.compiler_params(("parallel",), pipelined=huge)
 
 
 @pytest.mark.parametrize("schedule", ["k_inner", "a_resident", "b_resident"])

@@ -87,7 +87,7 @@ def test_aux_loss_decreases_for_balanced_router():
 def test_fsdp_specs_shard_params_over_data():
     from jax.sharding import AbstractMesh
     from repro.models.model import param_shapes
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     shapes = param_shapes(get_config("deepseek-v3-671b"))
     specs = shd.tree_param_specs(shapes, mesh, fsdp=True)
     moe_spec = specs["stage1"]["b0"]["moe"]

@@ -273,7 +273,7 @@ from jax.sharding import AbstractMesh, PartitionSpec as P  # noqa: E402
 
 from repro.distributed import sharding as shd  # noqa: E402
 
-PROP_MESH = AbstractMesh((("data", 4), ("model", 8)))
+PROP_MESH = AbstractMesh((4, 8), ("data", "model"))
 
 _axis_entries = st.sampled_from([None, "data", "model", ("data", "model")])
 _shapes = st.lists(st.integers(1, 512), min_size=1, max_size=4)

@@ -14,9 +14,8 @@ from repro.configs.base import all_arch_ids, get_config
 from repro.distributed import sharding as shd
 from repro.models.model import param_shapes
 
-# AbstractMesh takes ((name, size), ...) pairs in current JAX.
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_param_specs_cover_tree_and_divide():

@@ -284,7 +284,7 @@ def test_roofline_defaults_to_chip_links():
 
 # ------------------------------------------------------- mesh-axis bridge
 def test_matmul_shard_spec_from_mesh_axes():
-    mesh = AbstractMesh((("data", 4), ("model", 2)))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     spec = shd.matmul_shard_spec(mesh, batch_axes="data", n_axes="model")
     assert spec == ShardSpec(batch=4, n=2)
     col = shd.tp_matmul_spec(mesh, "col")
@@ -294,5 +294,5 @@ def test_matmul_shard_spec_from_mesh_axes():
     with pytest.raises(ValueError):
         shd.tp_matmul_spec(mesh, "diag")
     # model-only mesh: dp finds no data axes and stays unsharded on batch
-    tponly = shd.tp_matmul_spec(AbstractMesh((("model", 8),)), "col")
+    tponly = shd.tp_matmul_spec(AbstractMesh((8,), ("model",)), "col")
     assert tponly.n == 8 and tponly.batch == 1
