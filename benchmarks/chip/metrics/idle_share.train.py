@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the busiest
+chip of the mesh."""
+
+
+def read(rec):
+    tr = rec.get("trace")
+    if not tr:
+        return None
+    return 100.0 * (1.0 - tr["busy_s_busiest"] / tr["window_s"])
