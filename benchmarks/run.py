@@ -1290,6 +1290,9 @@ def tab_obs_trace(rec, ctx):
             "decode_spans": digest.get("decode", 0),
             "prefill_spans": digest.get("prefill", 0),
             "admit_spans": digest.get("admit", 0),
+            "sync_spans": digest.get("sync", 0),
+            "scatter_spans": digest.get("scatter", 0),
+            "bookkeep_spans": digest.get("bookkeep", 0),
             "chrome_events": len(chrome["traceEvents"]),
             "tuned_hits": snap.get("tuned_hits", 0),
             "tuned_misses": snap.get("tuned_misses", 0),

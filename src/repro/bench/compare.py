@@ -94,6 +94,9 @@ _EXACT_NAMES = frozenset(
         "decode_spans",
         "prefill_spans",
         "admit_spans",
+        "sync_spans",
+        "scatter_spans",
+        "bookkeep_spans",
         "drift_classes",
         "drift_accepted",
         "chrome_events",

@@ -170,11 +170,13 @@ def matmul(a: jax.Array, b: jax.Array, *, backend: str | None = None,
     for s in lead:
         batch *= s
     dtype_bytes = jnp.dtype(a.dtype).itemsize
-    # The dispatch span opens *before* planning so the tune lookup and
-    # the planner annotate this span (cache key, modeled_us) — the ops
+    # The named scope tags the device ops with the shape class.  The
+    # dispatch span opens *before* planning so the tune lookup and the
+    # planner annotate this span (cache key, modeled_us) — the ops
     # wrapper below joins it rather than opening a second one.
-    with _obs.dispatch("dense", m=m, k=k, n=n, batch=batch,
-                       backend=cfg.backend, epilogue=str(ep.spec)) as dsp:
+    with jax.named_scope("mm." + _obs.shape_class_token(m, k, n, batch)), \
+            _obs.dispatch("dense", m=m, k=k, n=n, batch=batch,
+                          backend=cfg.backend, epilogue=str(ep.spec)) as dsp:
         cost = plan_matmul(m, k, n, dtype_bytes=dtype_bytes, amp=cfg.amp,
                            chip=cfg.chip_spec, mode=cfg.plan_mode,
                            batch=batch, mesh_shape=cfg.mesh_shape,

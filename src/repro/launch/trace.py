@@ -2,15 +2,17 @@
 
   PYTHONPATH=src python -m repro.launch.trace --mode matmul --skew 64
   PYTHONPATH=src python -m repro.launch.trace --mode serve --out t.json
+  PYTHONPATH=src python -m repro.launch.trace --mode serve --profile DIR
 
 Arms `repro.obs.trace_scope` around a small real workload and shows
 what the instrumented stack emits: the deterministic text tree on
 stdout, the Chrome-trace JSON at ``--out`` (load it in Perfetto /
-chrome://tracing).  ``--clock sim`` (default) measures every dispatch
-at exactly its modeled time, so the trace is host-independent and the
-drift report comes back identically zero; ``--clock wall`` stamps real
-timestamps (`jax.block_until_ready` around each dispatch) so the same
-tree shows where the wall time actually went.
+chrome://tracing).  The sim clock measures every dispatch at exactly
+its modeled time, so the trace is host-independent and the drift report
+comes back identically zero.  ``--profile DIR`` instead arms the
+profiler sink inside a `jax.profiler` trace written under DIR: the
+spans land beside the device's ops on one clock (open it in TensorBoard
+or Perfetto), and the GC and JAX compile-stage totals are printed.
 
 ``--check`` turns the run into a smoke gate (CI's trace-smoke job):
 the Chrome document must schema-validate, its event count must equal
@@ -22,23 +24,33 @@ under ``--mm-plan-mode tuned``).  Exits non-zero on any violation.
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import config as mmcfg
 from repro.obs import (
+    REGISTRY,
     SimClock,
-    WallClock,
     drift_report,
     to_chrome,
     trace_scope,
     validate_chrome,
 )
+from repro.obs.spans import SINK_HISTOGRAMS
 
 
-def _make_clock(name: str):
-    return SimClock() if name == "sim" else WallClock()
+@contextlib.contextmanager
+def _traced(args):
+    """The span tree on the sim clock, or with ``--profile`` the
+    profiler sink inside a profiler trace written there."""
+    if not args.profile:
+        with trace_scope(clock=SimClock()) as tr:
+            yield tr
+        return
+    with jax.profiler.trace(args.profile), trace_scope(profiler=True) as tr:
+        yield tr
 
 
 def run_matmul(args):
@@ -52,7 +64,7 @@ def run_matmul(args):
         (args.size, k, args.size * args.skew),  # right-skewed
         (1, k, args.size),                  # decode GEMV row
     ]
-    with trace_scope(clock=_make_clock(args.clock)) as tr:
+    with _traced(args) as tr:
         for m, kk, n in shapes:
             a = jnp.ones((m, kk), jnp.float32)
             b = jnp.ones((kk, n), jnp.float32)
@@ -85,7 +97,7 @@ def run_serve(args):
         [(0, 3, 2), (1, 5, 1), (2, 7, 2)], vocab_size=cfg.vocab_size, seed=3
     )
     with tune_runtime.use_cache(cache), mmcfg.mm_config(plan_mode="tuned"):
-        with trace_scope(clock=_make_clock(args.clock)) as tr:
+        with _traced(args) as tr:
             sched = Scheduler(params, cfg, table)
             results = sched.run(reqs, max_ticks=50)
     if len(results) != len(reqs):
@@ -133,10 +145,9 @@ def check_trace(tr, *, tuned: bool) -> list[str]:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mode", choices=("matmul", "serve"), default="matmul")
-    ap.add_argument("--clock", choices=("sim", "wall"), default="sim",
-                    help="sim: measured == modeled exactly "
-                         "(host-independent); wall: perf_counter with "
-                         "block_until_ready")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="arm the profiler sink and write a profiler "
+                         "trace under DIR instead of the span tree")
     ap.add_argument("--out", default=None, metavar="PATH",
                     help="write the Chrome-trace JSON here")
     ap.add_argument("--size", type=int, default=128,
@@ -153,11 +164,21 @@ def main(argv=None) -> int:
                     help="suppress the span-tree dump")
     mmcfg.add_cli_args(ap)
     args = ap.parse_args(argv)
+    if args.profile and (args.out or args.check):
+        ap.error("--profile writes no span tree: drop --out / --check")
 
     with mmcfg.scope_from_args(args):
         tuned = args.mode == "serve" or mmcfg.resolve().plan_mode == "tuned"
         tr = run_matmul(args) if args.mode == "matmul" else run_serve(args)
 
+    if args.profile:
+        hists = REGISTRY.histograms()
+        for name in SINK_HISTOGRAMS:
+            h = hists.get(name)
+            if h is not None:
+                print(f"[trace] {name}: {h.total():.1f} over {h.count()}")
+        print(f"[trace] profile written under {args.profile}")
+        return 0
     if not args.quiet:
         print(tr.render().rstrip("\n"))
     digest = tr.digest()

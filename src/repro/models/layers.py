@@ -93,17 +93,19 @@ def mlp(x: jax.Array, p: dict, cfg, residual: jax.Array | None = None
     projection — each linear is a single planned kernel, no separate
     elementwise HBM pass.  The epilogue runs at fp32 accumulator width
     before the one cast to the native dtype (§Perf iteration B1 still
-    holds: matmuls accumulate fp32 inside skewmm)."""
-    if cfg.mlp_type == "swiglu":
-        g = skewmm.matmul(x, p["w_gate"], epilogue=Epilogue(act="silu"))
-        u = skewmm.matmul(x, p["w_up"])
-        h = g * u
-    else:
-        h = skewmm.matmul(x, p["w_up"], epilogue=Epilogue(act="gelu"))
-    if residual is not None:
-        return skewmm.matmul(h, p["w_down"],
-                             epilogue=Epilogue(residual=residual))
-    return skewmm.matmul(h, p["w_down"])
+    holds: matmuls accumulate fp32 inside skewmm).  Its device ops sit
+    under the named scope `mlp`."""
+    with jax.named_scope("mlp"):
+        if cfg.mlp_type == "swiglu":
+            g = skewmm.matmul(x, p["w_gate"], epilogue=Epilogue(act="silu"))
+            u = skewmm.matmul(x, p["w_up"])
+            h = g * u
+        else:
+            h = skewmm.matmul(x, p["w_up"], epilogue=Epilogue(act="gelu"))
+        if residual is not None:
+            return skewmm.matmul(h, p["w_down"],
+                                 epilogue=Epilogue(residual=residual))
+        return skewmm.matmul(h, p["w_down"])
 
 
 # ------------------------------------------------- blockwise attention (jnp)

@@ -89,12 +89,14 @@ def forward_hidden(params, cfg, tokens, *, prefix_embeds=None):
 
 
 def unembed(params, cfg, h):
-    """h (..., D) -> logits (..., V), final softcap applied, fp32."""
-    w = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
-    logits = skewmm.matmul(h, w, out_dtype=jnp.float32)
-    if cfg.final_softcap > 0.0:
-        logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
-    return logits
+    """h (..., D) -> logits (..., V), final softcap applied, fp32; under
+    the named scope `lm_head`."""
+    with jax.named_scope("lm_head"):
+        w = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
+        logits = skewmm.matmul(h, w, out_dtype=jnp.float32)
+        if cfg.final_softcap > 0.0:
+            logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
+        return logits
 
 
 def mtp_hidden(params, cfg, h, tokens):

@@ -5,11 +5,13 @@ Three pieces, one package:
 - **Span tree** (`spans`): thread-local `trace_scope()` arms tracing;
   hot paths emit `span()` / `event()` / `annotate()`.  Disarmed, every
   emit is one integer check (the `scrub` discipline) — zero cost on
-  jitted paths, no counters, no allocations.
+  jitted paths, no counters, no allocations.  `trace_scope(profiler=
+  True)` writes the spans into a running `jax.profiler` trace instead,
+  with GC pauses and JAX compile stages beside them.
 - **Metrics registry** (`metrics`): typed counters / gauges /
   histograms under one lock.  `guard.health` and `ServeTelemetry`
   both write here now.
-- **Attribution** (`clock`, `attribution`): an injectable clock stamps
+- **Attribution** (`clock`, `attribution`): the sim clock stamps
   `measured_us` on dispatch spans next to the planner's `modeled_us`;
   per-shape-class drift histograms feed `drift_report()`, judged
   against the calibration gate's `MAX_LOG_SPREAD`.
@@ -26,7 +28,7 @@ from repro.obs.attribution import (
     record_drift,
     shape_class_token,
 )
-from repro.obs.clock import SimClock, WallClock, make_clock
+from repro.obs.clock import SimClock
 from repro.obs.export import (
     digest,
     export_chrome,
@@ -65,7 +67,6 @@ __all__ = [
     "SimClock",
     "Span",
     "Trace",
-    "WallClock",
     "annotate",
     "current_span",
     "current_trace",
@@ -74,7 +75,6 @@ __all__ = [
     "drift_report",
     "event",
     "export_chrome",
-    "make_clock",
     "measured",
     "percentile_nearest_rank",
     "record_drift",

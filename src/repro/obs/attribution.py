@@ -5,8 +5,9 @@
 attribution pair into the metrics registry — per-shape-class drift
 histograms (`drift/<class>` observes log(measured/modeled)) plus the
 obs counters the `obs` bench suite gates integer-exact.  `measured()`
-routes the actual kernel thunk through the armed trace's clock so the
-span picks up `measured_us`.
+routes the actual kernel thunk through the armed trace's clock (the sim
+clock) so the span picks up `measured_us`.  Under the profiler sink a
+dispatch is a `repro.dispatch` annotation and nothing more.
 
 `drift_report()` turns the per-class histograms into the same
 fit-quality shape the calibration gate uses: a class is *accepted* when
@@ -56,8 +57,13 @@ def dispatch(site: str, **attrs: Any) -> Iterator[Span | Any]:
     doesn't carry yet instead of opening a second one — one logical
     dispatch is one span, one counter tick, one drift sample.
     """
-    if not _spans.tracing():
+    if not _spans._ARMED:
         yield NULL_SPAN
+        return
+    if not _spans.tracing():
+        # the profiler sink: an annotation around the dispatch, no tree
+        with _spans.span("dispatch"):
+            yield NULL_SPAN
         return
     enclosing = _spans.open_span("dispatch")
     if enclosing is not None:

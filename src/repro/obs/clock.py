@@ -1,73 +1,25 @@
-"""Attribution clocks — the injectable `measured_us` source.
+"""Attribution clock — the injectable `measured_us` source.
 
-`SimClock` is the CI surface: it "measures" a dispatch at exactly the
-cost model's prediction, so traces are deterministic, integer-exact
-across hosts, and per-class drift is identically zero — any non-zero
-drift in a sim-clock run means the modeled/measured plumbing itself
-broke.  `WallClock` is the live surface: `perf_counter` around the
-thunk with `jax.block_until_ready` on the result, the same async-
-dispatch discipline `bench.timing` uses, so measured_us covers device
-execution rather than dispatch enqueue.
+`SimClock` "measures" a dispatch at exactly the cost model's
+prediction, so traces are deterministic, integer-exact across hosts,
+and per-class drift is identically zero — any non-zero drift in a
+sim-clock run means the modeled/measured plumbing itself broke.
+
+There is no host-clock measurer: under `jit` a thunk's wall time is its
+tracing, not its execution.  Device time comes from a profiler trace,
+into which `trace_scope(profiler=True)` writes the spans and the named
+scopes tag the ops (`repro.obs.spans`).
 """
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable
 
 
 class SimClock:
     """Modeled measurer: measured == modeled, exactly."""
 
-    wall = False
-
     def measure(
         self, fn: Callable[[], Any], modeled_us: float | None = None
     ) -> tuple[Any, float | None]:
         return fn(), modeled_us
-
-
-class WallClock:
-    """perf_counter measurer with block_until_ready semantics."""
-
-    wall = True
-
-    def __init__(self):
-        self._t0 = time.perf_counter()
-
-    def now_us(self) -> float:
-        """Microseconds since this clock was armed (span timestamps)."""
-        return (time.perf_counter() - self._t0) * 1e6
-
-    def measure(
-        self, fn: Callable[[], Any], modeled_us: float | None = None
-    ) -> tuple[Any, float | None]:
-        del modeled_us
-        t0 = time.perf_counter()
-        out = fn()
-        out = self._block(out)
-        return out, (time.perf_counter() - t0) * 1e6
-
-    @staticmethod
-    def _block(out: Any) -> Any:
-        import jax
-
-        # Inside jit the output is a Tracer — blocking is meaningless
-        # (and an error); the measurement then covers trace time only.
-        if isinstance(out, jax.core.Tracer):
-            return out
-        try:
-            return jax.block_until_ready(out)
-        except Exception:
-            return out
-
-
-def make_clock(kind: str | None):
-    """CLI helper: 'sim' → SimClock, 'wall' → WallClock, None → None."""
-    if kind is None or kind == "none":
-        return None
-    if kind == "sim":
-        return SimClock()
-    if kind == "wall":
-        return WallClock()
-    raise ValueError(f"unknown clock kind {kind!r} (expected sim|wall|none)")

@@ -2,12 +2,12 @@
 
 `digest()` is the provenance fragment (span-kind counts, gated
 integer-exact by the `obs` bench suite).  `render_text()` is the
-test-facing exporter — stable ordering, no timestamps unless the wall
-clock stamped them.  `to_chrome()` emits the Chrome-tracing / Perfetto
-"traceEvents" document with complete ("ph": "X") events: real
-timestamps when the wall clock ran, otherwise a synthetic sequential
-layout (each span as wide as its measured_us, children packed in
-order) so sim-clock traces open identically on every host.
+test-facing exporter — stable ordering, no timestamps.  `to_chrome()`
+emits the Chrome-tracing / Perfetto "traceEvents" document with complete
+("ph": "X") events in a synthetic sequential layout (each span as wide
+as its measured_us, children packed in order), so traces open
+identically on every host.  Real timestamps come from the profiler sink
+(`trace_scope(profiler=True)`), in the profiler's own trace.
 """
 
 from __future__ import annotations
@@ -86,12 +86,8 @@ def to_chrome(trace: "Trace") -> dict[str, Any]:
         return args
 
     def emit(sp: "Span", ts: float) -> float:
-        """Emit span at ts; returns its duration.  Real timestamps win
-        when the wall clock stamped them."""
-        if sp.t0_us is not None and sp.t1_us is not None:
-            ts, dur = sp.t0_us, max(sp.t1_us - sp.t0_us, 0.0)
-        else:
-            dur = _synthetic_dur(sp)
+        """Emit span at ts; returns its duration."""
+        dur = _synthetic_dur(sp)
         events.append(
             {
                 "name": f"{sp.kind}:{sp.name}" if sp.name else sp.kind,

@@ -25,25 +25,53 @@ Span kinds emitted by the instrumented stack:
   validate   a pre-dispatch plan rejection (guard/validate)
   retry      a transient re-execution (guard/fallback.retry_call)
   tick       one scheduler step (serve/sched/loop); children admit /
-             prefill / decode
+             prefill / decode / sync / scatter / bookkeep
+  sync       a device-to-host read (argmax tokens, the NaN scrub)
+  scatter    KV slab growth and the scatter of prefilled rows
+  bookkeep   per-row token bookkeeping, completions, telemetry
 
 The tree itself is plain data (`Span`); exporters live in
 `repro.obs.export` and are reachable through `Trace.export_chrome` /
 `Trace.render` / `Trace.digest`.
+
+`trace_scope(profiler=True)` is the profiler sink: instead of building a
+tree, each `span(kind)` enters a `jax.profiler.TraceAnnotation` named
+``repro.<kind>``, so the spans land in the profiler's own trace on the
+clock of the device's ops.  While a sink is armed, garbage collections
+appear as ``repro.gc`` annotations and, with JAX's trace, lowering and
+compile-or-load durations, feed millisecond histograms of `REGISTRY`
+(`SINK_HISTOGRAMS`; the JAX stages also per ``<name>/<fun_name>``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import threading
+import time
 from typing import Any, Iterator
+
+from repro.obs.metrics import REGISTRY
 
 _TLS = threading.local()
 _ARM_LOCK = threading.Lock()
 # Process-wide count of open trace scopes: the disarmed fast path is one
 # falsy check on this int, before any thread-local attribute lookup.
 _ARMED = 0
+# Open profiler sinks; the GC callback and the JAX listener are
+# registered while this is non-zero.
+_SINKS = 0
+_ANNOTATION: Any = None          # jax.profiler.TraceAnnotation, once armed
+_GC_OPEN: list[tuple[Any, float]] = []
+
+# JAX monitoring duration events -> REGISTRY histograms (milliseconds)
+JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_ms",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_ms",
+    "/jax/core/compile/backend_compile_duration": "jax_compile_or_load_ms",
+}
+SINK_HISTOGRAMS = ("gc_ms", *JAX_STAGES.values())
 
 
 @dataclasses.dataclass
@@ -53,8 +81,6 @@ class Span:
     `modeled_us` / `measured_us` are the attribution pair: the cost
     model's prediction and the armed clock's observation for the same
     region (either may be absent).  Everything else rides in `attrs`.
-    `t0_us` / `t1_us` are wall timestamps, recorded only by the wall
-    clock (the sim clock keeps traces host-independent).
     """
 
     kind: str
@@ -63,8 +89,6 @@ class Span:
     children: list["Span"] = dataclasses.field(default_factory=list)
     modeled_us: float | None = None
     measured_us: float | None = None
-    t0_us: float | None = None
-    t1_us: float | None = None
 
     def set(self, **attrs: Any) -> "Span":
         """Merge attributes; modeled_us / measured_us land on the typed
@@ -143,6 +167,7 @@ class Trace:
 class _Layer:
     trace: Trace
     open: list[Span] = dataclasses.field(default_factory=list)
+    profiler: bool = False
 
 
 def _layers() -> list[_Layer]:
@@ -153,8 +178,13 @@ def _layers() -> list[_Layer]:
 
 
 def tracing() -> bool:
-    """Is a trace scope armed on *this* thread?  The hot-path check."""
-    return bool(_ARMED) and bool(getattr(_TLS, "layers", None))
+    """Is a tree-building trace scope innermost on *this* thread?  The
+    hot-path check: call sites compute span attributes only when it
+    holds, and the profiler sink builds no tree."""
+    if not _ARMED:
+        return False
+    layers = getattr(_TLS, "layers", None)
+    return bool(layers) and not layers[-1].profiler
 
 
 def current_trace() -> Trace | None:
@@ -195,31 +225,94 @@ def open_span(kind: str) -> Span | None:
 
 
 @contextlib.contextmanager
-def trace_scope(clock: Any = None) -> Iterator[Trace]:
+def trace_scope(clock: Any = None, *, profiler: bool = False) -> Iterator[Trace]:
     """Arm structured tracing for the dynamic extent of the block.
 
     Layered like `mm_config()`: scopes nest (spans land in the innermost
     trace), the stack is thread-local, and exit always restores the
-    enclosing state.  `clock` is an attribution clock (`SimClock` /
-    `WallClock` from `repro.obs.clock`, or None for structure-only
-    traces); dispatch sites consult it through `measured()`.
+    enclosing state.  `clock` is an attribution clock (`SimClock` from
+    `repro.obs.clock`, or None for structure-only traces); dispatch
+    sites consult it through `measured()`.
 
         with trace_scope(clock=SimClock()) as tr:
             out = skew_matmul(a, b)
         tr.export_chrome("trace.json")
+
+    With `profiler=True` the layer is the profiler sink (module
+    docstring): spans become `repro.<kind>` annotations in a running
+    `jax.profiler` trace, the yielded `Trace` stays empty, and the GC
+    callback and JAX listener are registered until the last sink exits.
     """
     global _ARMED
-    layer = _Layer(trace=Trace(clock=clock))
+    if profiler and clock is not None:
+        raise ValueError("the profiler sink takes no attribution clock")
+    layer = _Layer(trace=Trace(clock=clock), profiler=profiler)
     layers = _layers()
     layers.append(layer)
     with _ARM_LOCK:
+        if profiler:
+            _arm_sink()
         _ARMED += 1
     try:
         yield layer.trace
     finally:
         with _ARM_LOCK:
             _ARMED -= 1
+            if profiler:
+                _disarm_sink()
         layers.pop()
+
+
+def _arm_sink() -> None:
+    """Register the GC callback and the JAX listener (first sink only);
+    called under `_ARM_LOCK`."""
+    global _SINKS, _ANNOTATION
+    _SINKS += 1
+    if _SINKS > 1:
+        return
+    import jax
+    import jax.monitoring
+
+    _ANNOTATION = jax.profiler.TraceAnnotation
+    gc.callbacks.append(_on_gc)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _disarm_sink() -> None:
+    global _SINKS
+    _SINKS -= 1
+    if _SINKS:
+        return
+    import jax.monitoring
+
+    gc.callbacks.remove(_on_gc)
+    jax.monitoring.unregister_event_duration_listener(_on_jax_duration)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook: a `repro.gc` annotation over each collection,
+    its pause in the `gc_ms` histogram."""
+    del info
+    if phase == "start":
+        ann = _ANNOTATION("repro.gc")
+        ann.__enter__()
+        _GC_OPEN.append((ann, time.perf_counter()))
+    elif _GC_OPEN:
+        ann, t0 = _GC_OPEN.pop()
+        ann.__exit__(None, None, None)
+        REGISTRY.observe("gc_ms", (time.perf_counter() - t0) * 1e3)
+        REGISTRY.inc("gc_collections")
+
+
+def _on_jax_duration(event: str, secs: float, **kw: Any) -> None:
+    name = JAX_STAGES.get(event)
+    if name is None:
+        return
+    ms = secs * 1e3
+    REGISTRY.observe(name, ms)
+    fun = kw.get("fun_name")
+    if fun:
+        REGISTRY.observe(f"{name}/{fun}", ms)
 
 
 @contextlib.contextmanager
@@ -237,19 +330,19 @@ def span(kind: str, name: str = "", **attrs: Any) -> Iterator[Span | _NullSpan]:
         yield NULL_SPAN
         return
     layer = layers[-1]
+    if layer.profiler:
+        # TraceMe would fold keyword arguments into the name: pass none
+        with _ANNOTATION(f"repro.{kind}"):
+            yield NULL_SPAN
+        return
     sp = Span(kind=kind, name=name)
     sp.set(**attrs)
     parent = layer.open[-1] if layer.open else None
     (parent.children if parent is not None else layer.trace.roots).append(sp)
     layer.open.append(sp)
-    clock = layer.trace.clock
-    if clock is not None and getattr(clock, "wall", False):
-        sp.t0_us = clock.now_us()
     try:
         yield sp
     finally:
-        if clock is not None and getattr(clock, "wall", False):
-            sp.t1_us = clock.now_us()
         layer.open.pop()
 
 
@@ -261,6 +354,8 @@ def event(kind: str, name: str = "", **attrs: Any) -> Span | _NullSpan:
     if not layers:
         return NULL_SPAN
     layer = layers[-1]
+    if layer.profiler:
+        return NULL_SPAN
     sp = Span(kind=kind, name=name)
     sp.set(**attrs)
     parent = layer.open[-1] if layer.open else None

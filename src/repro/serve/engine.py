@@ -22,6 +22,7 @@ from repro.core import skewmm
 from repro.models import attention as attn_mod
 from repro.models import layers, moe, rglru, ssm, transformer
 from repro.models.layers import rmsnorm
+from repro.obs import spans as _obs
 from repro.serve import kvcache
 
 
@@ -50,15 +51,18 @@ def _block_prefill(x, p, cfg: ModelConfig, kind: str, positions, max_len):
                                   window=window)
         else:
             q, k, v = attn_mod.gqa_project(h, p["attn"], cfg, positions)
-            entry = {"k": _place_kv(k, clen), "v": _place_kv(v, clen)}
             b, s, _ = h.shape
-            ctx = layers.blockwise_attention(
-                jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                jnp.swapaxes(v, 1, 2), causal=True, window=window,
-                softcap=cfg.attn_softcap,
-                q_positions=positions, kv_positions=positions)
-            ctx = jnp.swapaxes(ctx, 1, 2).reshape(
-                b, s, cfg.n_heads * cfg.head_dim)
+            # the projections stay outside: their ops are under `mm.*`
+            with jax.named_scope("attention"):
+                with jax.named_scope("kv_write"):
+                    entry = {"k": _place_kv(k, clen), "v": _place_kv(v, clen)}
+                ctx = layers.blockwise_attention(
+                    jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                    jnp.swapaxes(v, 1, 2), causal=True, window=window,
+                    softcap=cfg.attn_softcap,
+                    q_positions=positions, kv_positions=positions)
+                ctx = jnp.swapaxes(ctx, 1, 2).reshape(
+                    b, s, cfg.n_heads * cfg.head_dim)
             h = skewmm.matmul(ctx, p["attn"]["wo"])
     elif kind == "ssm":
         h, entry = _ssm_prefill(h, p["mixer"], cfg)
@@ -169,28 +173,30 @@ def _decode_gqa(h, p, cfg: ModelConfig, entry, pos, window):
     is_ring = window is not None
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
-        q, k_new, v_new = attn_mod.gqa_project(
-            h, p, cfg, jnp.full((1,), pos, jnp.int32))
-        slot = jnp.mod(pos, clen) if is_ring else pos
-        k_cache = jax.lax.dynamic_update_slice(
-            entry["k"], k_new, (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            entry["v"], v_new, (0, slot, 0, 0))
         q_pos = jnp.full((1,), pos, jnp.int32)
     else:
-        q, k_new, v_new = attn_mod.gqa_project(h, p, cfg, pos[:, None])
-        slot = jnp.mod(pos, clen) if is_ring else pos
-        rows = jnp.arange(b)
-        k_cache = entry["k"].at[rows, slot].set(k_new[:, 0])
-        v_cache = entry["v"].at[rows, slot].set(v_new[:, 0])
         q_pos = pos[:, None]
-    kv_pos = kvcache.kv_slot_positions(pos, clen, is_ring)
-    ctx = layers.blockwise_attention(
-        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k_cache, 1, 2),
-        jnp.swapaxes(v_cache, 1, 2),
-        causal=True, window=window, softcap=cfg.attn_softcap,
-        q_positions=q_pos, kv_positions=kv_pos)
-    ctx = jnp.swapaxes(ctx, 1, 2).reshape(b, 1, hq * hd)
+    q, k_new, v_new = attn_mod.gqa_project(h, p, cfg, q_pos)
+    slot = jnp.mod(pos, clen) if is_ring else pos
+    # the projections stay outside: their ops are under `mm.*`
+    with jax.named_scope("attention"):
+        with jax.named_scope("kv_write"):
+            if pos.ndim == 0:
+                k_cache = jax.lax.dynamic_update_slice(
+                    entry["k"], k_new, (0, slot, 0, 0))
+                v_cache = jax.lax.dynamic_update_slice(
+                    entry["v"], v_new, (0, slot, 0, 0))
+            else:
+                rows = jnp.arange(b)
+                k_cache = entry["k"].at[rows, slot].set(k_new[:, 0])
+                v_cache = entry["v"].at[rows, slot].set(v_new[:, 0])
+        kv_pos = kvcache.kv_slot_positions(pos, clen, is_ring)
+        ctx = layers.blockwise_attention(
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k_cache, 1, 2),
+            jnp.swapaxes(v_cache, 1, 2),
+            causal=True, window=window, softcap=cfg.attn_softcap,
+            q_positions=q_pos, kv_positions=kv_pos)
+        ctx = jnp.swapaxes(ctx, 1, 2).reshape(b, 1, hq * hd)
     out = skewmm.matmul(ctx, p["wo"])
     return out, {"k": k_cache, "v": v_cache}
 
@@ -364,15 +370,21 @@ def guarded_decode_step(params, cfg: ModelConfig, cache, tokens, pos,
 
     logits, new_cache = decode_step(params, cfg, cache, tokens, pos, mm)
     logits, injected = _faults.maybe_poison(logits, "decode")
-    if bool(jnp.isfinite(logits).all()):
+    if _all_finite(logits):
         return logits, new_cache
     if injected:
         _health.record("faults_caught", injected)
     _health.record("scrubbed_batches")
     with mmcfg.scope(mm), mmcfg.mm_config(backend="xla"):
         logits, new_cache = decode_step(params, cfg, cache, tokens, pos)
-    if not bool(jnp.isfinite(logits).all()):
+    if not _all_finite(logits):
         raise NumericFault(
             "decode_step logits non-finite even on the XLA reference "
             "backend")
     return logits, new_cache
+
+
+def _all_finite(logits) -> bool:
+    """The scrub's host read: it waits for the step on the device."""
+    with _obs.span("sync"):
+        return bool(jnp.isfinite(logits).all())

@@ -23,6 +23,12 @@ proves the slots ship full.
 
 Everything model-facing is eager (not jitted): the guard scrub needs
 concrete logits, and health counters must record per call.
+
+Spans (`repro.obs`, one integer check each when disarmed): a `tick`
+holds `admit` (with `prefill` per group) and `decode`, each model call
+(trace, lower, load, enqueue); `sync` around every device-to-host read;
+`scatter` around slab growth and the scatter of prefilled rows;
+`bookkeep` around the per-row token loop, completions and telemetry.
 """
 
 from __future__ import annotations
@@ -126,6 +132,10 @@ class Scheduler:
         if required <= cur:
             return
         new_b = self.table.batch_bucket(required)
+        with _obs.span("scatter"):
+            self._grow_slab(cur, new_b)
+
+    def _grow_slab(self, cur: int, new_b: int) -> None:
         if self._slab is None:
             self._slab = kvcache.init_cache(self.cfg, new_b, self.table.max_len)
             self._free = kvcache.SlotFreeList(new_b)
@@ -169,19 +179,26 @@ class Scheduler:
                 last_index=jnp.asarray(last),
             )
         )
-        first = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        if self.trace_logits:
-            rows_np = np.asarray(logits)
-            for i, r in enumerate(reqs):
-                self.logit_trace[r.rid] = [rows_np[i]]
+        with _obs.span("sync"):
+            first = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            if self.trace_logits:
+                rows_np = np.asarray(logits)
+                for i, r in enumerate(reqs):
+                    self.logit_trace[r.rid] = [rows_np[i]]
         rows = np.asarray([self._free.alloc() for _ in reqs], np.int32)
         # pad-on-device stays on device: scatter the n real rows into the
         # slab at their allocated slots (unpad-on-fetch).
-        self._slab = jax.tree.map(
-            lambda slab, new: slab.at[:, rows].set(new[:, :n]),
-            self._slab,
-            cache,
-        )
+        with _obs.span("scatter"):
+            self._slab = jax.tree.map(
+                lambda slab, new: slab.at[:, rows].set(new[:, :n]),
+                self._slab,
+                cache,
+            )
+        with _obs.span("bookkeep"):
+            self._admit_rows(reqs, rows, first, now)
+
+    def _admit_rows(self, reqs: list[Request], rows: np.ndarray,
+                    first: np.ndarray, now: int) -> None:
         self.telemetry.prefill_batches += 1
         for i, r in enumerate(reqs):
             row = int(rows[i])
@@ -224,8 +241,14 @@ class Scheduler:
                     jnp.asarray(self._pos),
                 )
             )
-        tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        logits_np = np.asarray(logits) if self.trace_logits else None
+        with _obs.span("sync"):
+            tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            logits_np = np.asarray(logits) if self.trace_logits else None
+        with _obs.span("bookkeep"):
+            self._advance_rows(tok, logits_np, now)
+
+    def _advance_rows(self, tok: np.ndarray, logits_np: np.ndarray | None,
+                      now: int) -> None:
         self.telemetry.decode_steps += 1
         for row in sorted(self.live):
             lv = self.live[row]

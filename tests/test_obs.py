@@ -18,14 +18,12 @@ from repro.obs import (
     REGISTRY,
     Registry,
     SimClock,
-    WallClock,
     annotate,
     current_span,
     current_trace,
     drift_report,
     event,
     export_chrome,
-    make_clock,
     percentile_nearest_rank,
     render_text,
     span,
@@ -280,21 +278,6 @@ class TestAttribution:
         assert rungs
         assert rungs[-1].name in ("tuned", "modeled")
 
-    def test_wall_clock_records_nonzero_measured(self):
-        a, b = _mats()
-        with trace_scope(clock=WallClock()) as tr:
-            ops.skew_matmul(a, b)
-        (sp,) = [s for s in tr.spans() if s.kind == "dispatch"]
-        assert sp.measured_us is not None and sp.measured_us > 0
-        assert sp.t0_us is not None and sp.t1_us is not None
-        assert sp.t1_us >= sp.t0_us
-
-    def test_make_clock(self):
-        assert isinstance(make_clock("sim"), SimClock)
-        assert isinstance(make_clock("wall"), WallClock)
-        assert make_clock("none") is None
-        assert make_clock(None) is None
-
     def test_drift_report_threshold(self):
         REGISTRY.histogram("drift/m1k2n3b1").observe(MAX_LOG_SPREAD * 2)
         REGISTRY.histogram("drift/m4k2n3b1").observe(MAX_LOG_SPREAD / 2)
@@ -348,13 +331,6 @@ class TestExport:
                                 "args": {}}]}
         with pytest.raises(ValueError):
             validate_chrome(bad)
-
-    def test_wall_clock_real_timestamps(self):
-        with trace_scope(clock=WallClock()) as tr:
-            with span("tick", "t0"):
-                pass
-        (ev,) = to_chrome(tr)["traceEvents"]
-        assert ev["ts"] >= 0
 
 
 # ---------------------------------------------------------- provenance
@@ -462,3 +438,158 @@ class TestConcurrency:
             ops.skew_matmul(a, b)
         assert health.get("obs_dispatches") == 1  # post-reset dispatch only
         assert len([s for s in tr.spans() if s.kind == "dispatch"]) == 2
+
+
+# -------------------------------------------------------- profiler sink
+def _tiny_scheduler():
+    import jax
+
+    from repro.configs.base import get_config
+    from repro.models.model import build_model
+    from repro.serve.sched import BucketTable, Scheduler, scripted_trace
+
+    cfg = get_config("phi4-mini-3.8b").reduced()
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    table = BucketTable.for_workload(max_batch=2, max_prompt=8, max_new=3)
+    sched = Scheduler(params, cfg, table)
+    for r in scripted_trace([(0, 3, 3), (0, 5, 3)],
+                            vocab_size=cfg.vocab_size, seed=1):
+        sched.submit(r)
+    return sched
+
+
+def _sink_hooks():
+    import gc
+
+    from jax._src import monitoring
+
+    return (obs_spans._on_gc in gc.callbacks,
+            obs_spans._on_jax_duration
+            in monitoring.get_event_duration_listeners())
+
+
+class _NoThreadLocal:
+    """Stands in for the span stack: any look at it fails the test."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"disarmed site read the span stack ({name})")
+
+
+class TestProfilerSink:
+    def test_disarmed_step_is_one_integer_check(self, monkeypatch):
+        sched = _tiny_scheduler()
+        sched.step()                     # admit + prefill + decode
+        before = REGISTRY.snapshot()
+        monkeypatch.setattr(obs_spans, "_TLS", _NoThreadLocal())
+        sched.step()                     # decode, bookkeeping, completion
+        sched.step()
+        assert sched.results
+        assert _sink_hooks() == (False, False)
+        assert REGISTRY.snapshot() == before
+
+    def test_sink_registers_hooks_only_while_armed(self):
+        assert _sink_hooks() == (False, False)
+        with trace_scope(profiler=True):
+            assert _sink_hooks() == (True, True)
+            with trace_scope(profiler=True):
+                assert _sink_hooks() == (True, True)
+            assert _sink_hooks() == (True, True)   # the outer sink holds
+        assert _sink_hooks() == (False, False)
+        assert obs_spans._ARMED == 0 and obs_spans._SINKS == 0
+
+    def test_sink_builds_no_tree(self):
+        a, b = _mats()
+        with trace_scope(profiler=True) as tr:
+            assert not tracing()      # call sites build no span attributes
+            with span("tick", "t0", tick=0) as sp:
+                assert sp is NULL_SPAN
+                assert event("plan", "p") is NULL_SPAN
+                assert not annotate(x=1)
+                assert current_span() is None
+            ops.skew_matmul(a, b)
+        assert tr.roots == []
+        assert health.get("obs_dispatches") == 0
+        with pytest.raises(ValueError):
+            with trace_scope(SimClock(), profiler=True):
+                pass
+
+    def test_fresh_jit_and_gc_feed_the_histograms(self):
+        import gc
+
+        import jax
+
+        def fresh_fn(x):
+            return x * 3 + 1
+
+        with trace_scope(profiler=True):
+            jax.jit(fresh_fn)(jnp.ones(4)).block_until_ready()
+            gc.collect()
+        hists = REGISTRY.histograms()
+        assert hists["jax_trace_ms"].count() >= 1
+        assert hists["jax_trace_ms"].total() > 0
+        assert hists["jax_trace_ms/fresh_fn"].count() == 1
+        assert hists["jax_compile_or_load_ms"].count() >= 1
+        assert hists["gc_ms"].count() >= 1
+        assert REGISTRY.value("gc_collections") == hists["gc_ms"].count()
+        # disarmed again: a fresh jit adds nothing
+        n = hists["jax_trace_ms"].count()
+        jax.jit(lambda x: x - 2)(jnp.ones(4)).block_until_ready()
+        assert REGISTRY.histograms()["jax_trace_ms"].count() == n
+
+    @pytest.fixture(scope="class")
+    def step_trace(self, tmp_path_factory):
+        """Host events [(name, start_ns, end_ns)] of an admitting and a
+        decoding `step()`, in a profiler trace with the sink armed,
+        read through JAX's own `ProfileData`."""
+        import glob
+        import gc
+
+        import jax
+        from jax.profiler import ProfileData
+
+        sched = _tiny_scheduler()
+        log_dir = str(tmp_path_factory.mktemp("profile"))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("window"), \
+                    trace_scope(profiler=True):
+                sched.step()
+                sched.step()
+                gc.collect()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+        return sorted(
+            ((e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events),
+            key=lambda ev: ev[1])
+
+    def test_spans_nest_in_tick_in_window(self, step_trace):
+        def inside(inner, outer):
+            return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+        (window,) = [e for e in step_trace if e[0] == "window"]
+        ticks = [e for e in step_trace if e[0] == "repro.tick"]
+        assert len(ticks) == 2 and all(inside(t, window) for t in ticks)
+        for kind in ("admit", "prefill", "decode", "sync", "scatter",
+                     "bookkeep", "dispatch"):
+            found = [e for e in step_trace if e[0] == f"repro.{kind}"]
+            assert found, kind
+            assert all(any(inside(e, t) for t in ticks) for e in found)
+        # the decoding tick reads its tokens after its model call
+        decode = [e for e in step_trace if e[0] == "repro.decode"
+                  and inside(e, ticks[1])]
+        assert decode and any(
+            e[0] == "repro.sync" and inside(e, ticks[1])
+            and e[1] >= decode[-1][2] for e in step_trace)
+        assert any(e[0] == "repro.gc" and inside(e, window)
+                   for e in step_trace)
+
+    def test_span_names_carry_no_arguments(self, step_trace):
+        names = {e[0] for e in step_trace if e[0].startswith("repro.")}
+        assert {"repro.tick", "repro.decode", "repro.sync"} <= names
+        assert not any("#" in n for n in names)
