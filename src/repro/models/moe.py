@@ -47,6 +47,12 @@ def track_capacity_slots():
         _TRACK_SLOTS = prev
 
 
+def tracking_capacity_slots() -> bool:
+    """Is `track_capacity_slots` armed?  It records while tracing, so a
+    caller that holds compiled programs runs eagerly while it is."""
+    return _TRACK_SLOTS
+
+
 def init_moe(key, cfg) -> dict:
     d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
     dt = layers.dtype_of(cfg)

@@ -31,12 +31,16 @@ def percentile_nearest_rank(values: list[float], p: float) -> float:
 
 
 class Counter:
-    """Monotonic integer counter."""
+    """Monotonic integer counter.  ``health=False`` keeps it out of
+    `Registry.counts()`, the guard-health ledger that gated benchmark
+    records print: for counters of how the program ran (compiles, eager
+    fallbacks), not of what went wrong."""
 
     kind = "counter"
 
-    def __init__(self, name: str, lock: threading.RLock):
+    def __init__(self, name: str, lock: threading.RLock, health: bool = True):
         self.name = name
+        self.health = health
         self._lock = lock
         self._value = 0
 
@@ -150,8 +154,8 @@ class Registry:
                 )
         return m
 
-    def counter(self, name: str) -> Counter:
-        return self._get(name, Counter)
+    def counter(self, name: str, health: bool = True) -> Counter:
+        return self._get(name, Counter, health=health)
 
     def gauge(self, name: str, mode: str = "last") -> Gauge:
         return self._get(name, Gauge, mode=mode)
@@ -176,11 +180,13 @@ class Registry:
 
     def counts(self) -> dict[str, int]:
         """Non-zero counter + gauge values as a sorted int dict — the
-        `health.snapshot()` compatibility surface."""
+        `health.snapshot()` compatibility surface (counters made with
+        ``health=False`` left out)."""
         with self._lock:
             out = {}
             for name, m in self._metrics.items():
-                if isinstance(m, (Counter, Gauge)):
+                if isinstance(m, Gauge) or (
+                        isinstance(m, Counter) and m.health):
                     v = m.value()
                     if v:
                         out[name] = int(v)

@@ -5,6 +5,8 @@ layer (lax.scan's ys gives the layer-stacked cache for free);
 `decode_step` advances one token against the cache.  Both are pure
 functions of (params, cache, ...) so they pjit/shard cleanly; batch dims
 shard over "data", heads/latents over "model" (see distributed.sharding).
+`DecodeExecutables` holds `decode_step` compiled once per slab shape and
+matmul context, the serving loop's step.
 
 Decode-time attention is the maximally skewed matmul regime of the paper
 (m = batch rows vs n = 32k+ cache columns); the MLA path additionally uses
@@ -19,11 +21,14 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import config as mmcfg
 from repro.core import skewmm
+from repro.guard import validate as _guard
 from repro.models import attention as attn_mod
 from repro.models import layers, moe, rglru, ssm, transformer
 from repro.models.layers import rmsnorm
 from repro.obs import spans as _obs
+from repro.obs.metrics import REGISTRY
 from repro.serve import kvcache
+from repro.tune import runtime as _tune
 
 
 # =====================================================================
@@ -344,8 +349,83 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos,
         return logits, new_cache
 
 
+# =====================================================================
+# compiled decode
+# =====================================================================
+def _eager_context() -> bool:
+    """Is a per-call host fact armed that a compiled step would hide?
+    Guard machinery (a fault scope's kernel-site faults fire while
+    tracing; a tripped ladder is re-read per dispatch), a tree-building
+    trace scope (per-matmul dispatch spans, the sim clock), or MoE
+    capacity-slot tracking (records per call).  The profiler sink builds
+    no tree and leaves the compiled step on."""
+    return (_guard.engaged() or _obs.tracing()
+            or moe.tracking_capacity_slots())
+
+
+class DecodeExecutables:
+    """`decode_step`, compiled once per slab shape and matmul context.
+
+    Called like `decode_step`.  A program is keyed by `cfg`, the shapes
+    of the cache, tokens and positions, the resolved `mm_config` and,
+    under ``plan_mode="tuned"``, the identity of the active tune cache
+    (held with the program, so the identity stays unique): all the trace
+    reads, so no program runs under another plan than its own.  Params
+    and cache are arguments, never constants, and nothing is donated:
+    the NaN scrub re-runs a step from the old cache.  While
+    `_eager_context()` holds, calls run `decode_step` eagerly and
+    nothing is compiled.
+
+    REGISTRY counters, kept out of the health ledger:
+    `serve_decode_compiles` (programs traced) and `serve_decode_eager`
+    (calls run eagerly).
+    """
+
+    def __init__(self):
+        self._programs: dict[tuple, tuple[object, jax.stages.Compiled]] = {}
+
+    def __call__(self, params, cfg: ModelConfig, cache, tokens, pos,
+                 mm: mmcfg.MatmulConfig | None = None):
+        if _eager_context():
+            REGISTRY.counter("serve_decode_eager", health=False).inc()
+            return decode_step(params, cfg, cache, tokens, pos, mm)
+        pos = jnp.asarray(pos, jnp.int32)
+        with mmcfg.scope(mm):
+            program = self._program(params, cfg, cache, tokens, pos)
+        return program(params, cache, tokens, pos)
+
+    def warm(self, params, cfg: ModelConfig, batch: int, max_len: int) -> None:
+        """Compile ahead, under the current context, the step on a slab of
+        `batch` rows and `max_len` positions (no slab is allocated)."""
+        if _eager_context():
+            return
+        cache = jax.eval_shape(lambda: kvcache.init_cache(cfg, batch, max_len))
+        rows = jax.ShapeDtypeStruct((batch,), jnp.int32)
+        self._program(params, cfg, cache, rows, rows)
+
+    def _program(self, params, cfg, cache, tokens, pos) -> jax.stages.Compiled:
+        mm = mmcfg.current()
+        # only a tuned plan reads the cache (and reading it may load it)
+        tuned = _tune.get_active_cache() if mm.plan_mode == "tuned" else None
+        key = (cfg, mm, id(tuned), tokens.shape, pos.shape,
+               tuple(x.shape for x in jax.tree.leaves(cache)))
+        held = self._programs.get(key)
+        if held is None:
+
+            def step(params, cache, tokens, pos):
+                REGISTRY.counter("serve_decode_compiles", health=False).inc()
+                return decode_step(params, cfg, cache, tokens, pos)
+
+            # a fresh function per key: JAX's trace cache cannot see the
+            # matmul context, so it must never hand back another's jaxpr
+            program = jax.jit(step).lower(params, cache, tokens, pos).compile()
+            held = self._programs[key] = (tuned, program)
+        return held[1]
+
+
 def guarded_decode_step(params, cfg: ModelConfig, cache, tokens, pos,
-                        mm: mmcfg.MatmulConfig | None = None):
+                        mm: mmcfg.MatmulConfig | None = None, *,
+                        step=decode_step):
     """`decode_step` with a serving-boundary NaN scrub.
 
     Decode is where a poisoned kernel is most damaging — one non-finite
@@ -362,13 +442,15 @@ def guarded_decode_step(params, cfg: ModelConfig, cache, tokens, pos,
     health ("scrubbed_batches"); a step whose *reference* re-run still
     produces non-finite logits raises `NumericFault` (genuinely bad
     params/inputs — no backend can fix that, and returning it would be a
-    silent escape).
+    silent escape).  `step` runs the model step: `decode_step`, or a
+    `DecodeExecutables` holder, whose reference re-run then gets its own
+    program under ``backend="xla"``.
     """
     from repro.guard import faults as _faults
     from repro.guard import health as _health
     from repro.guard.fallback import NumericFault
 
-    logits, new_cache = decode_step(params, cfg, cache, tokens, pos, mm)
+    logits, new_cache = step(params, cfg, cache, tokens, pos, mm)
     logits, injected = _faults.maybe_poison(logits, "decode")
     if _all_finite(logits):
         return logits, new_cache
@@ -376,7 +458,7 @@ def guarded_decode_step(params, cfg: ModelConfig, cache, tokens, pos,
         _health.record("faults_caught", injected)
     _health.record("scrubbed_batches")
     with mmcfg.scope(mm), mmcfg.mm_config(backend="xla"):
-        logits, new_cache = decode_step(params, cfg, cache, tokens, pos)
+        logits, new_cache = step(params, cfg, cache, tokens, pos)
     if not _all_finite(logits):
         raise NumericFault(
             "decode_step logits non-finite even on the XLA reference "

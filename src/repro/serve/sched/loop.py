@@ -14,26 +14,33 @@ One `Scheduler.step()` is one simulated tick:
    solo decode — tested).  The slab only grows, by zero-padding the
    batch axis to the next bucket (`kvcache.pad_axis`).
 
-Decode runs through `guarded_decode_step`, so the PR 6 ladder is never
+Decode runs through `guarded_decode_step`, so the guard ladder is never
 bypassed: a poisoned batch is scrubbed on the XLA reference backend and
 healthy requests keep their rows (chaos-tested).  MoE models batch every
 live request's expert GEMMs in the same capacity slots simply by
 decoding jointly; with `track_capacity_slots` armed the health ledger
 proves the slots ship full.
 
-Everything model-facing is eager (not jitted): the guard scrub needs
-concrete logits, and health counters must record per call.
+The decode model step is compiled (`engine.DecodeExecutables`): one
+program per slab batch bucket, built ahead when the scheduler is made
+for every bucket up to `max_live`, so a tick traces, lowers and loads
+nothing.  The guard scrub's host read stays outside it.  Where a
+per-call host fact is the point (a fault scope or tripped ladder, a
+tree-building trace scope, MoE slot tracking) the step runs eagerly, as
+prefill always does.
 
 Spans (`repro.obs`, one integer check each when disarmed): a `tick`
 holds `admit` (with `prefill` per group) and `decode`, each model call
-(trace, lower, load, enqueue); `sync` around every device-to-host read;
-`scatter` around slab growth and the scatter of prefilled rows;
-`bookkeep` around the per-row token loop, completions and telemetry.
+(eager: trace, lower, load, enqueue; compiled: enqueue); `sync` around
+every device-to-host read; `scatter` around slab growth and the scatter
+of prefilled rows; `bookkeep` around the per-row token loop, completions
+and telemetry.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +114,13 @@ class Scheduler:
         self._free: kvcache.SlotFreeList | None = None
         self._tokens: np.ndarray | None = None  # (B,) last token per row
         self._pos: np.ndarray | None = None  # (B,) next write position
+        # the decode step, compiled ahead for every slab size it can grow to
+        self._decode = engine.DecodeExecutables()
+        top = table.batch_bucket(self.policy.max_live)
+        for b in table.batch_buckets:
+            if b <= top:
+                self._model_call(lambda b=b: self._decode.warm(
+                    params, cfg, b, table.max_len))
 
     # ------------------------------------------------------------- intake
     @property
@@ -230,7 +244,9 @@ class Scheduler:
 
     # ------------------------------------------------------------ decode
     def _decode_all(self, now: int) -> None:
-        step_fn = engine.guarded_decode_step if self.guard else engine.decode_step
+        step_fn = (functools.partial(engine.guarded_decode_step,
+                                     step=self._decode)
+                   if self.guard else self._decode)
         with _obs.span("decode", batch=len(self._tokens), live=len(self.live)):
             logits, self._slab = self._model_call(
                 lambda: step_fn(

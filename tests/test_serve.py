@@ -116,6 +116,7 @@ def test_mla_cache_is_compressed():
 
 
 # ---------------------------------------------------------------- sched
+from repro.core import skewmm                      # noqa: E402
 from repro.core.config import mm_config            # noqa: E402
 from repro.guard import faults as gfaults          # noqa: E402
 from repro.guard import health as ghealth          # noqa: E402
@@ -129,8 +130,10 @@ from repro.serve.sched import (                    # noqa: E402
     min_full_batch,
     scripted_trace,
 )
+from repro.obs import REGISTRY, trace_scope        # noqa: E402
 from repro.serve.sched.buckets import bucket_up    # noqa: E402
 from repro.tune import runtime as tune_runtime     # noqa: E402
+from repro.tune.cache import TuneCache             # noqa: E402
 
 
 def _sched_model(arch="phi4-mini-3.8b"):
@@ -214,35 +217,56 @@ def test_scheduler_completes_and_respects_admission_bound():
 
 def test_join_leave_logits_bit_identical_to_solo_decode():
     """Continuous batching must not perturb survivors: every logits row,
-    across joins, leaves and slab growth, equals a solo decode exactly."""
+    across joins, leaves and slab growth, equals the same request decoded
+    alone at the same row of a slab of the same batch bucket, through the
+    same compiled step.  (A batch-1 decode is no reference: it reduces in
+    another order.)"""
     cfg, params = _sched_model()
     table = BucketTable.for_workload(max_batch=4, max_prompt=16, max_new=4)
     entries = [(0, 3, 4), (0, 5, 3), (1, 9, 4), (2, 2, 3)]
     trace = scripted_trace(entries, vocab_size=cfg.vocab_size, seed=7)
     sched = Scheduler(params, cfg, table, guard=False, trace_logits=True)
-    results = sched.run(trace, max_ticks=50)
-    assert len(results) == len(trace)
-    assert sched.slab_batch == 4        # the slab grew 2 -> 4 mid-run
+    for r in trace:
+        sched.submit(r)
+    rows, slab_at = {}, []     # rid -> slab row; tick -> its decode's batch
+    while sched.queue or sched.live:
+        sched.step()
+        slab_at.append(sched.slab_batch)
+        rows.update((lv.req.rid, row) for row, lv in sched.live.items())
+    assert len(sched.results) == len(trace)
+    assert slab_at[0] == 2 and sched.slab_batch == 4   # grew 2 -> 4 mid-run
+    compiles = REGISTRY.value("serve_decode_compiles")
 
     for req in trace:
         pb = table.prompt_bucket(req.prompt_len)
         toks = np.zeros((1, pb), np.int32)
         toks[0, :req.prompt_len] = req.tokens
-        cache, logits = engine.prefill(
+        solo, logits = engine.prefill(
             params, cfg, jnp.asarray(toks), max_len=table.max_len,
             last_index=jnp.asarray([req.prompt_len - 1]))
         want = [np.asarray(logits)[0]]
-        tok, pos = int(jnp.argmax(logits[0])), req.prompt_len
-        for _ in range(req.max_new - 1):
-            logits, cache = engine.decode_step(
-                params, cfg, cache, jnp.asarray([tok], jnp.int32),
-                jnp.asarray(pos, jnp.int32))
-            want.append(np.asarray(logits)[0])
-            tok, pos = int(jnp.argmax(logits[0])), pos + 1
+        row = rows[req.rid]
+        admit = req.arrival + sched.results[req.rid]["ttft"] - 1
+        slab = jax.tree.map(
+            lambda x: jnp.zeros((x.shape[0], slab_at[admit], *x.shape[2:]),
+                                x.dtype).at[:, [row]].set(x), solo)
+        tok, pos = int(np.argmax(want[0])), req.prompt_len
+        for tick in range(admit, admit + req.max_new - 1):
+            b = slab_at[tick]
+            slab = jax.tree.map(lambda x: kvcache.pad_axis(x, 1, b), slab)
+            tokens = np.zeros(b, np.int32)
+            positions = np.zeros(b, np.int32)
+            tokens[row], positions[row] = tok, pos
+            logits, slab = sched._decode(params, cfg, slab,
+                                         jnp.asarray(tokens),
+                                         jnp.asarray(positions))
+            want.append(np.asarray(logits)[row])
+            tok, pos = int(np.argmax(want[-1])), pos + 1
         got = sched.logit_trace[req.rid]
         assert len(got) == len(want) == req.max_new
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
+    assert REGISTRY.value("serve_decode_compiles") == compiles
 
 
 def test_scheduler_tuned_mode_zero_misses():
@@ -306,3 +330,122 @@ def test_moe_capacity_slots_full_when_batched():
     # snapshot() drops zero counters: absent == zero underfilled
     assert snap.get("moe_slots_underfilled", 0) == 0
     assert snap["moe_slots_filled"] == snap["moe_slots_total"]
+
+
+# ------------------------------------------------------ compiled decode
+def _counts():
+    return (REGISTRY.value("serve_decode_compiles"),
+            REGISTRY.value("serve_decode_eager"))
+
+
+def test_decode_compiles_once_per_slab_bucket():
+    cfg, params = _sched_model()
+    table = BucketTable.for_workload(max_batch=4, max_prompt=8, max_new=4,
+                                     min_batch=2)
+    assert table.batch_buckets == (2, 4)
+    trace = scripted_trace([(0, 3, 4), (0, 5, 3), (1, 6, 4), (2, 2, 3)],
+                           vocab_size=cfg.vocab_size, seed=4)
+    c0, e0 = _counts()
+    sched = Scheduler(params, cfg, table)
+    assert _counts() == (c0 + 2, e0)          # built ahead, one per bucket
+    sizes = []
+    for r in trace:
+        sched.submit(r)
+    while sched.queue or sched.live:
+        sched.step()
+        sizes.append(sched.slab_batch)
+    assert sizes[0] == 2 and 4 in sizes       # the slab grew 2 -> 4
+    assert len(sched.results) == len(trace)
+    assert _counts() == (c0 + 2, e0)          # no tick traced anything
+    snap = ghealth.snapshot()
+    assert "serve_decode_compiles" not in snap
+    assert "serve_decode_eager" not in snap
+
+
+def _decoding_scheduler(cfg, params):
+    """A scheduler whose two requests are both admitted at tick 0 and
+    decode for five more ticks: later ticks admit (and plan) nothing."""
+    table = BucketTable.for_workload(max_batch=2, max_prompt=8, max_new=7,
+                                     min_batch=2)
+    sched = Scheduler(params, cfg, table)
+    for r in scripted_trace([(0, 3, 7), (0, 5, 7)],
+                            vocab_size=cfg.vocab_size, seed=6):
+        sched.submit(r)
+    sched.step()
+    assert sched.n_live == 2
+    return sched
+
+
+def test_decode_recompiles_under_a_new_plan_context():
+    """A changed `mm_config` or tune cache traces anew; a program is
+    never reused under another plan, and each is reused under its own."""
+    cfg, params = _sched_model()
+    sched = _decoding_scheduler(cfg, params)
+    c0, e0 = _counts()
+    with mm_config(plan_mode="naive"), skewmm.plan_capture() as log:
+        sched.step()
+    assert _counts() == (c0 + 1, e0)
+    assert log                                # planned anew, in that mode
+    with skewmm.plan_capture() as log:
+        sched.step()                          # the first program again
+    assert _counts() == (c0 + 1, e0)
+    assert not log
+
+    cache_a, cache_b = TuneCache(), TuneCache()
+    with mm_config(plan_mode="tuned"):
+        with tune_runtime.use_cache(cache_a):
+            sched.step()
+        with tune_runtime.use_cache(cache_b):
+            sched.step()
+        assert _counts() == (c0 + 3, e0)
+        with tune_runtime.use_cache(cache_a):
+            sched.step()
+    assert _counts() == (c0 + 3, e0)
+    assert sorted(sched.results) == [0, 1]    # the fifth step decoded
+
+
+def test_compiled_decode_matches_eager_decode_step():
+    cfg, params = _sched_model()
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (2, 8)), jnp.int32)
+    cache, logits = engine.prefill(params, cfg, toks, max_len=12)
+    tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    pos = jnp.asarray([8, 8], jnp.int32)
+    c0, _ = _counts()
+    got_logits, got_cache = engine.DecodeExecutables()(
+        params, cfg, cache, tokens, pos)
+    assert _counts()[0] == c0 + 1
+    want_logits, want_cache = engine.decode_step(params, cfg, cache, tokens,
+                                                 pos)
+    np.testing.assert_allclose(np.asarray(got_logits),
+                               np.asarray(want_logits), rtol=1e-5, atol=1e-5)
+    for g, w in zip(jax.tree.leaves(got_cache), jax.tree.leaves(want_cache)):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("scope, eager", [
+    ("fault_scope", True),
+    ("trace_scope", True),
+    ("profiler_sink", False),
+])
+def test_decode_runs_eagerly_only_under_a_per_call_context(scope, eager):
+    """A fault scope or a tree-building trace scope needs the step traced
+    on every call; the profiler sink builds no tree and stays compiled."""
+    cfg, params = _sched_model()
+    sched = _decoding_scheduler(cfg, params)
+    ctx = {
+        "fault_scope": lambda: gfaults.fault_scope(kinds=("nan_output",),
+                                                   rate=0.0),
+        "trace_scope": trace_scope,
+        "profiler_sink": lambda: trace_scope(profiler=True),
+    }[scope]
+    c0, e0 = _counts()
+    steps = sched.telemetry.decode_steps
+    with ctx():
+        while sched.live:
+            sched.step()
+    ran = sched.telemetry.decode_steps - steps
+    assert ran == 5
+    assert _counts() == (c0, e0 + ran if eager else e0)
+
