@@ -4,7 +4,13 @@ mixes, per-layer metric readers and correctness limits.
 Everything that belongs to one configuration, mix, metric or cell is a
 file of its own under the benchmark's root:
 
-  configs/<config>.json      the configuration as it is run
+  configs/<config>.json      the configuration as it is run; it may name
+                             `"program"` and names `"reference"`
+  configs/<program>.py       `model_config(c, name)`, the program's
+                             ModelConfig, and `dims(c)`, the object that
+                             counts the work (flops.py); without
+                             `"program"`, configs/dense_gqa_program.py
+  configs/<reference>.py     the plain reference that decides `correct`
   traffic/<mix>.json         the mix's parameters (read by traffic.py)
   metrics/<metric>.py        `read(record) -> float | None`
   limits/<cell>.json         {number: limit} for the correctness check
@@ -22,6 +28,7 @@ import sys
 
 HERE = pathlib.Path(__file__).resolve().parent
 REPO = HERE.parents[1]
+DEFAULT_PROGRAM = "dense_gqa_program"
 
 
 def annotate(name: str, on: bool, **kw):
@@ -93,43 +100,24 @@ def per_layer(bench: dict, cell_name: str) -> list[dict]:
     return out
 
 
-def program_config(c: dict, name: str):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    if c.get("partial_rotary_factor", 1.0) != 1.0 or c.get("rope_scaling"):
-        raise ValueError(f"{name}: the program runs plain RoPE only")
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"{name}: only SwiGLU MLPs are described here")
-    return ModelConfig(
-        name=name, family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim",
-                       c["hidden_size"] // c["num_attention_heads"]),
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        mlp_type="swiglu", rope_theta=c["rope_theta"],
-        tie_embeddings=c["tie_word_embeddings"], norm_eps=c["rms_norm_eps"],
-        dtype=c["torch_dtype"])
-
-
-def dims(c: dict):
-    from chip.flops import Dims
-
-    return Dims(layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-                heads=c["num_attention_heads"],
-                kv_heads=c["num_key_value_heads"],
-                head_dim=c.get("head_dim",
-                               c["hidden_size"] // c["num_attention_heads"]),
-                d_ff=c["intermediate_size"], vocab=c["vocab_size"])
+def program(c: dict, root: pathlib.Path = HERE):
+    """The configuration's program module: `model_config(c, name)` and
+    `dims(c)` (see configs/dense_gqa_program.py, the default where the
+    file names no `"program"`)."""
+    return _module(c.get("program", DEFAULT_PROGRAM), root)
 
 
 def reference(c: dict, root: pathlib.Path = HERE):
-    """The configuration's plain reference module, beside its file (or
-    among the benchmark's own)."""
-    path = root / "configs" / f"{c['reference']}.py"
+    """The configuration's plain reference module."""
+    return _module(c["reference"], root)
+
+
+def _module(name: str, root: pathlib.Path):
+    """configs/<name>.py beside the configuration's file, or among the
+    benchmark's own."""
+    path = root / "configs" / f"{name}.py"
     if not path.exists():
-        path = HERE / "configs" / f"{c['reference']}.py"
+        path = HERE / "configs" / f"{name}.py"
     return _load(path)
 
 

@@ -1,0 +1,9 @@
+"""Milliseconds JAX spent tracing, lowering, and compiling or loading
+programs inside the window (its monitoring durations), per scheduler
+tick, in the chat cell."""
+
+from chip.stats import compile_ms_per_tick
+
+
+def read(rec):
+    return compile_ms_per_tick(rec)

@@ -42,20 +42,28 @@ from chip import harness, peaks, traffic, trace_reduce  # noqa: E402
 EXIT_REFUSED = 3
 
 
+# JAX's durations of tracing, lowering, and compiling or loading a program
+STAGES = ("/jax/core/compile/jaxpr_trace_duration",
+          "/jax/core/compile/jaxpr_to_mlir_module_duration",
+          "/jax/core/compile/backend_compile_duration")
+
+
 class CompileCounter:
-    """Executables compiled or loaded from the persistent cache, counted
-    from JAX's monitoring events since `reset()`."""
+    """Executables compiled or loaded from the persistent cache, and the
+    time JAX spent in its compile stages, counted from JAX's monitoring
+    events since `reset()`."""
 
     def __init__(self):
         import jax.monitoring as mon
 
-        self.loads = 0
-        self.hits = 0
+        self.reset()
         mon.register_event_duration_secs_listener(self._duration)
         mon.register_event_listener(self._event)
 
     def _duration(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
+        if name in STAGES:
+            self.stage_s += secs
+        if name == STAGES[2]:
             self.loads += 1
 
     def _event(self, name, **kw):
@@ -64,11 +72,13 @@ class CompileCounter:
 
     def reset(self) -> "CompileCounter":
         self.loads = self.hits = 0
+        self.stage_s = 0.0
         return self
 
     def read(self) -> dict:
         return {"loaded_or_compiled": self.loads, "cache_hits": self.hits,
-                "compiled": self.loads - self.hits}
+                "compiled": self.loads - self.hits,
+                "stage_ms": self.stage_s * 1e3}
 
 
 class Context:
@@ -81,7 +91,8 @@ class Context:
         self.config = harness.config(cell["config"], root)
         self.mix = traffic.load(cell["traffic"], root)
         self.limits = harness.limits(cell["name"], root)
-        self.dims = harness.dims(self.config)
+        self.program = harness.program(self.config, root)
+        self.dims = self.program.dims(self.config)
         self.devices = devices
         self.peaks = peaks.for_kind(devices[0].device_kind) \
             if devices[0].platform == "tpu" else None
@@ -149,6 +160,7 @@ def reduce_trace(rec: dict, path: str, devices,
     uses, to the run record.  Without a device plane (a CPU rehearsal)
     nothing is added."""
     tr = trace_reduce.load(path)
+    hlo = rec.pop("decode_hlo", None)
     used = {f"/device:TPU:{d.id}" for d in devices}
     if used & set(tr.device):
         tr.device = {p: ev for p, ev in tr.device.items() if p in used}
@@ -162,6 +174,7 @@ def reduce_trace(rec: dict, path: str, devices,
     _, a, b = windows[0]
     spans = [s for s in tr.host if a <= s[1] and s[2] <= b]
     steps = [s for s in spans if s[0].startswith(rec["step_span"])]
+    program = [s for s in tr.program if a <= s[1] and s[2] <= b]
     merged = {d: trace_reduce.merge(ev) for d, ev in tr.device.items()}
     busy = {d: trace_reduce.busy_within(m, a, b) * 1e-9
             for d, m in merged.items()}
@@ -177,9 +190,19 @@ def reduce_trace(rec: dict, path: str, devices,
                         trace_reduce.span_busy(merged[busiest], steps)],
         "collective_s_most": max(collective.values()),
     }
+    if hlo is not None:
+        decode = [s for s, st in zip(steps, rec["steps"])
+                  if not st["admit"] and st["positions"]]
+        rec["trace"]["decode_scope_s"] = {
+            k: v * 1e-9 for k, v in trace_reduce.scope_split(
+                tr.device[busiest], tr.modules.get(busiest, []),
+                hlo, decode).items()}
+        rec["trace"]["decode_scope_ticks"] = len(decode)
     rec["breakdown"] = {
         "device_ops": trace_reduce.top_ops(tr.device[busiest], a, b),
-        "idle_gaps": trace_reduce.idle_gaps(merged[busiest], a, b, spans),
+        "idle_gaps": trace_reduce.idle_gaps(
+            merged[busiest], a, b,
+            sorted(spans + program, key=lambda s: (s[1], -s[2]))),
     }
 
 

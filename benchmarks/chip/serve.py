@@ -5,9 +5,15 @@ configuration's plain reference.
 Set-up builds the weights on the device from the seed, warms every
 prefill shape the mix can issue, sends the first requests (one per
 client, or `max_live` for an open loop) and steps until they are all
-live.  The window then runs for `--seconds`.  A token is stamped with the
-time the `step()` that produced it returned (the step ends in a host
-sync, its argmax).
+live.  The window then runs for `--seconds` (or, where the mix gives
+`window_tokens`, until that many tokens are stamped: toy rehearsals,
+whose work must not depend on how fast the CPU is).  A token is stamped
+with the time the `step()` that produced it returned (the step ends in a
+host sync, its argmax).
+
+A traced run also records the HLO of the compiled decode programs the
+scheduler holds, for the device time per named scope
+(`trace_reduce.scope_split`).
 """
 
 from __future__ import annotations
@@ -17,7 +23,7 @@ import time
 
 import numpy as np
 
-from chip import flops, harness, traffic
+from chip import flops, harness, trace_reduce, traffic
 from chip.stats import percentile
 
 
@@ -55,7 +61,7 @@ def run(ctx, t0: float) -> dict:
     from repro.serve.sched.buckets import bucket_up
 
     mix, c, seed = ctx.mix, ctx.config, ctx.seed
-    cfg = harness.program_config(c, ctx.cell["config"])
+    cfg = ctx.program.model_config(c, ctx.cell["config"])
     gen = traffic.ServeTraffic(mix, seed, cfg.vocab_size)
     live_cap = mix["max_live"]
     params = build_model(cfg).init(jax.random.PRNGKey(traffic.seed32(seed)))
@@ -87,10 +93,12 @@ def run(ctx, t0: float) -> dict:
 
     ticks: list[dict] = []
     free_clients: list[tuple[int, float]] = []
+    stamped = 0
 
     def step(trace: bool) -> float:
         """One `Scheduler.step()`; stamps the tokens it produced and frees
         the clients whose requests completed."""
+        nonlocal stamped
         admit = any(n == 0 for n in seen.values()) and sched.n_live < live_cap
         t0 = time.perf_counter()
         name = "bench.tick.admit" if admit else "bench.tick.decode"
@@ -107,6 +115,7 @@ def run(ctx, t0: float) -> dict:
                 r = reqs[rid]
                 if n > before:
                     r.stamps.extend([t1] * (n - before))
+                    stamped += n - before
                     seen[rid] = n
                     if before == 0:
                         prefill.append(len(r.prompt))
@@ -142,7 +151,9 @@ def run(ctx, t0: float) -> dict:
     deadline = t_open + ctx.seconds
     next_due = t_open + (gen.gap_s(len(reqs)) if open_loop else 0.0)
     t = t_open
-    while t < deadline:
+    stamped = 0
+    target = mix.get("window_tokens")
+    while stamped < target if target else t < deadline:
         with harness.annotate("bench.generator", ctx.trace):
             now = time.perf_counter()
             if open_loop:
@@ -158,7 +169,9 @@ def run(ctx, t0: float) -> dict:
                 free_clients.clear()
         if not seen:
             # nothing to serve until the next arrival
-            time.sleep(max(0.0, min(next_due, deadline) - time.perf_counter()))
+            with harness.annotate("bench.wait", ctx.trace):
+                time.sleep(max(0.0, min(next_due, deadline)
+                               - time.perf_counter()))
             t = time.perf_counter()
             continue
         t = step(ctx.trace)
@@ -177,6 +190,8 @@ def run(ctx, t0: float) -> dict:
     rec["memory_peak_bytes"] = memory_peak
     if trace_path:
         rec["trace_path"] = trace_path
+        rec["decode_hlo"] = [trace_reduce.hlo_ops(p.as_text())
+                             for p in decode_programs(sched)]
 
     # every token served so far, of finished requests and of those still
     # streaming when the window closed
@@ -198,6 +213,13 @@ def run(ctx, t0: float) -> dict:
                           "control_widest_gap_logits": low,
                           "served_tokens_checked": n}
     return rec
+
+
+def decode_programs(sched) -> list:
+    """The compiled decode programs (`engine.DecodeExecutables`, one per
+    slab bucket) that the scheduler holds.  The holder has no public
+    accessor, so its private table is read here."""
+    return [compiled for _, compiled in sched._decode._programs.values()]
 
 
 def _tokens_of(sched, rid):

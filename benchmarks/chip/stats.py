@@ -41,3 +41,22 @@ def compiles_per_tick(rec: dict) -> float | None:
     if not ticks:
         return None
     return rec["compile_counts"]["loaded_or_compiled"] / ticks
+
+
+def compile_ms_per_tick(rec: dict) -> float | None:
+    """Milliseconds JAX spent tracing, lowering, and compiling or loading
+    programs inside the window, per tick."""
+    ticks = len(rec["steps"])
+    if not ticks:
+        return None
+    return rec["compile_counts"]["stage_ms"] / ticks
+
+
+def decode_scope_ms(rec: dict, scope: str) -> float | None:
+    """Device time under a named scope per traced pure-decode tick (the
+    ticks of `decode_device_ms`), or None where the scope read nothing."""
+    tr = rec.get("trace") or {}
+    split = tr.get("decode_scope_s")
+    if not split or not split[scope]:
+        return None
+    return split[scope] / tr["decode_scope_ticks"] * 1e3

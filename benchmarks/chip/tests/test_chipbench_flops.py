@@ -1,4 +1,5 @@
-"""flops.py against counts made by hand for phi4-mini-3.8b."""
+"""flops.py, through the default program module's dims, against counts
+made by hand for phi4-mini-3.8b."""
 
 import pathlib
 import sys
@@ -7,9 +8,10 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
 
-from chip import flops  # noqa: E402
+from chip import flops, harness  # noqa: E402
 
-PHI4 = flops.Dims(layers=32, d_model=3072, heads=24, kv_heads=8, head_dim=128,
+DENSE = harness.program({})
+PHI4 = DENSE.Dims(layers=32, d_model=3072, heads=24, kv_heads=8, head_dim=128,
                   d_ff=8192, vocab=200064)
 
 
@@ -40,7 +42,7 @@ def test_prefill_counts_each_causal_key_once():
 
 
 def test_train_step_by_hand():
-    l8 = flops.Dims(layers=8, d_model=3072, heads=24, kv_heads=8,
+    l8 = DENSE.Dims(layers=8, d_model=3072, heads=24, kv_heads=8,
                     head_dim=128, d_ff=8192, vocab=200064)
     # forward per token: 2 x (8 layers + head) + attention over a mean of
     # 1024.5 keys (4 x 8 x 24 x 128 = 98,304 per key); backward twice that

@@ -72,7 +72,7 @@ def run(ctx, t0: float) -> dict:
     from repro.train.trainer import Trainer, TrainerConfig
 
     c, mix = ctx.config, ctx.mix
-    cfg = harness.program_config(c, ctx.cell["config"])
+    cfg = ctx.program.model_config(c, ctx.cell["config"])
     opt_cfg = c["optimizer"]
     batch, seq = mix["batch"], mix["seq_len"]
     source = traffic.TrainBatches(mix, ctx.seed, cfg.vocab_size)
